@@ -64,7 +64,7 @@ bench-obs:
 bench-wire-proc:
 	python benchmarks/wire_proc.py
 
-# one traced workers=4 window -> benchmarks/TRACE_multi_channel.json
+# one traced workers=4 window -> benchmarks/TRACE_multi_channel/ (profiler capture)
 # (load in Perfetto / chrome://tracing)
 trace-shard:
 	python benchmarks/multi_channel.py --trace

@@ -251,20 +251,31 @@ def run(duration: float = 0.8, repeats: int = 3,
 
 
 def _traced_window(duration: float, service_us: float) -> None:
-    """``--trace``: one fully-observed workers=4 saturation window. The
-    span timeline (queued -> drain -> plane_lock -> pipeline phases ->
-    switch ops, one track per channel) lands in
-    benchmarks/TRACE_multi_channel.json — load it in Perfetto / Chrome
-    ``about:tracing`` — and the per-channel drain-wait p99 + switch CHR
-    come straight out of ``metrics_snapshot()``."""
+    """``--trace``: one fully-observed workers=4 saturation window under
+    a ``jax.profiler`` capture written to benchmarks/TRACE_multi_channel/
+    (open it in TensorBoard's profile plugin, or load its
+    ``.trace.json.gz`` in ui.perfetto.dev): each drain worker's line
+    holds its ``inc.drain > inc.pass > ...`` spans beside the device
+    ops, and the per-channel drain-wait p99 + switch CHR come straight
+    out of ``metrics_snapshot()``."""
     from pathlib import Path
-    inc.obs.enable(trace=True, trace_stride=4)
+
+    import jax
+
+    from repro.obs.trace import captured_spans
+    out = Path(__file__).resolve().parent / "TRACE_multi_channel"
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0          # the spans, not every frame
+    inc.obs.enable()
     try:
-        res = _drive(mk_services(), 4, duration, service_us)
+        with jax.profiler.trace(str(out), create_perfetto_trace=True,
+                                profiler_options=opts):
+            res = _drive(mk_services(), 4, duration, service_us)
         snap = res["snapshot"]
-        out = Path(__file__).resolve().parent / "TRACE_multi_channel.json"
-        inc.obs.write_trace(out)
-        print(f"trace: {len(inc.obs.tracer())} events -> {out}")
+        for line, evs in sorted(captured_spans(out).items()):
+            drains = sum(nm == "inc.drain" for nm, *_ in evs)
+            print(f"trace: {line}: {drains} inc.drain, {len(evs)} inc.* "
+                  f"spans -> {out}")
         for app, ch in sorted(snap["channels"].items()):
             print(f"{app}: drain_wait_p99_us="
                   f"{ch.get('drain_wait_p99_us', 0.0)}"
@@ -285,7 +296,8 @@ def main() -> None:
                     help="append the rows to benchmarks/results.csv")
     ap.add_argument("--trace", action="store_true",
                     help="one traced workers=4 window instead of the sweep:"
-                         " writes benchmarks/TRACE_multi_channel.json"
+                         " a profiler capture in benchmarks/"
+                         "TRACE_multi_channel/"
                          " (Perfetto-loadable) + the obs snapshot summary")
     ap.add_argument("--duration", type=float, default=0.8)
     ap.add_argument("--repeats", type=int, default=3)

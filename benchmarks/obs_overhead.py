@@ -10,8 +10,9 @@ fresh ``inc.NetRPC()`` per replay):
             runs byte-identical code to the baseline: the measured delta
             IS the box's timing noise floor, and the <= 2% gate asserts
             the disabled mode is indistinguishable from no obs at all.
-  on        ``obs.enable(trace=True, trace_stride=16)`` — per-batch
-            metrics plus sampled span tracing; gate <= 10% vs baseline.
+  on        ``obs.enable()`` — per-batch metrics plus the data plane's
+            spans (profiler annotations, no capture running); gate <=
+            10% vs baseline.
 
 Legs interleave per repeat so box jitter lands on every mode alike; each
 mode reports the fastest of ``repeats`` replays (min is the least-noise
@@ -20,11 +21,13 @@ Box-weather guard like multi_channel: when a gate fails, two extra off
 legs re-run interleaved — if identical code cannot hold a 2% self-ratio
 the row reports PASS-BASELINE-ALSO-FAILS instead of a bare FAIL.
 
-A fourth (untimed) leg runs a traced ``IncRuntime(workers=2)`` workload
-and validates the exports end-to-end: ``metrics_snapshot()`` against the
-checked-in ``scripts/obs_schema.json`` (per-channel submit->resolve p99
-and the switch CHR must be readable), and the Chrome trace JSON via
-``repro.obs.trace.validate_chrome_trace``.
+A fourth (untimed) leg runs an observed ``IncRuntime(workers=2)``
+workload under a ``jax.profiler`` capture and validates the exports
+end-to-end: ``metrics_snapshot()`` against the checked-in
+``scripts/obs_schema.json`` (per-channel submit->resolve p99 and the
+switch CHR must be readable), and the capture must hold every drained
+batch's ``inc.drain`` span with its ``inc.pass`` on the drain workers'
+host lines (``repro.obs.trace.captured_spans``).
 
     PYTHONPATH=src python -m benchmarks.obs_overhead [--smoke]
 """
@@ -38,18 +41,20 @@ if __package__ in (None, ""):            # executed as a bare script
     sys.path.insert(0, str(_root / "src"))
 
 import gc
+import tempfile
 import time
+
+import jax
 
 import repro.api as inc
 from repro.obs import schema as obs_schema
-from repro.obs.trace import validate_chrome_trace
+from repro.obs.trace import captured_spans
 from benchmarks._util import write_bench_json
 from benchmarks.agg_goodput import BatchBench, _batch_requests, _chunks
 
 BS = 64                      # the batch sweep's best-throughput point
 DISABLED_GATE_PCT = 2.0      # obs compiled out when off
-ENABLED_GATE_PCT = 10.0      # sampled tracing + metrics on the hot path
-TRACE_STRIDE = 16
+ENABLED_GATE_PCT = 10.0      # spans + metrics on the hot path
 
 
 def _time_leg(n_calls: int) -> float:
@@ -74,7 +79,7 @@ def _time_leg(n_calls: int) -> float:
 
 def _set_mode(mode: str) -> None:
     if mode == "on":
-        inc.obs.enable(trace=True, trace_stride=TRACE_STRIDE)
+        inc.obs.enable()
     else:
         inc.obs.disable()
 
@@ -96,8 +101,7 @@ def run_legs(n_calls: int, repeats: int) -> dict[str, float]:
             try:
                 times[mode].append(_time_leg(n_calls))
             finally:
-                # drop the sampled ring + registry deltas between legs so
-                # the enabled leg never times against a half-full ring
+                # drop the registry deltas between legs
                 inc.obs.disable()
                 inc.obs.reset()
     return {m: min(ts) for m, ts in times.items()}
@@ -117,18 +121,24 @@ def _self_ratio(n_calls: int, repeats: int) -> float:
 
 
 def _validate_exports(n_calls: int) -> tuple[int, dict]:
-    """The untimed correctness leg: traced async runtime workload; raises
-    unless the snapshot matches scripts/obs_schema.json, the quantile /
-    CHR keys the ISSUE promises are readable, and the Chrome trace
-    validates. Returns (n_trace_events, snapshot)."""
-    inc.obs.enable(trace=True, trace_stride=1)
+    """The untimed correctness leg: observed async runtime workload under
+    a profiler capture; raises unless the snapshot matches
+    scripts/obs_schema.json, the quantile / CHR keys are readable, and
+    the capture holds the drained batches' spans. Returns
+    (n_captured_spans, snapshot)."""
+    inc.obs.enable()
+    trace_dir = tempfile.TemporaryDirectory()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     try:
         with inc.IncRuntime(workers=2) as rt:
             stub = rt.make_stub(BatchBench, n_slots=8192)
-            futs = [stub.Push(**req) for req in _batch_requests(n_calls)]
-            rt.drain()
-            for f in futs:
-                f.result()
+            with jax.profiler.trace(trace_dir.name, profiler_options=opts):
+                futs = [stub.Push(**req)
+                        for req in _batch_requests(n_calls)]
+                rt.drain()
+                for f in futs:
+                    f.result()
             snap = rt.metrics_snapshot()
         obs_schema.validate(snap,
                             obs_schema.load(obs_schema.repo_schema_path()))
@@ -140,13 +150,16 @@ def _validate_exports(n_calls: int) -> tuple[int, dict]:
         chr_ = snap["switch"]["apps"]["BB-1"]["cache_hit_ratio"]
         if not (0.0 <= chr_ <= 1.0):
             raise AssertionError(f"cache_hit_ratio out of range: {chr_}")
-        trace_doc = inc.obs.chrome_trace()
-        validate_chrome_trace(trace_doc)
-        n_events = len(trace_doc["traceEvents"])
-        if n_events == 0:
-            raise AssertionError("traced run recorded no events")
-        return n_events, snap
+        spans = captured_spans(trace_dir.name)
+        evs = [e for line, es in spans.items() for e in es]
+        drains = {e[3]["seq"] for e in evs if e[0] == "inc.drain"}
+        passes = {e[3]["seq"] for e in evs if e[0] == "inc.pass"}
+        if not drains or not drains <= passes:
+            raise AssertionError(f"capture lacks the drained batches' "
+                                 f"spans: {sorted(spans)}")
+        return len(evs), snap
     finally:
+        trace_dir.cleanup()
         inc.obs.disable()
         inc.obs.reset()
 
@@ -179,10 +192,10 @@ def run(n_calls: int = 256, repeats: int = 5) -> tuple[list, dict]:
     rows.append(("obs/disabled_overhead_pct", round(pct["disabled"], 2),
                  f"need <= {DISABLED_GATE_PCT}%: {verdicts['disabled']}"))
     rows.append(("obs/enabled_overhead_pct", round(pct["on"], 2),
-                 f"metrics+trace(stride={TRACE_STRIDE})"
-                 f" need <= {ENABLED_GATE_PCT}%: {verdicts['on']}"))
+                 f"metrics+spans need <= {ENABLED_GATE_PCT}%: "
+                 f"{verdicts['on']}"))
     rows.append(("obs/export_validation", n_events,
-                 "snapshot schema + p50/p99 + CHR + chrome trace: PASS"))
+                 "snapshot schema + p50/p99 + CHR + captured spans: PASS"))
     overall = ("PASS" if set(verdicts.values()) == {"PASS"}
                else "PASS-BASELINE-ALSO-FAILS"
                if "FAIL" not in verdicts.values() else "FAIL")
@@ -191,7 +204,6 @@ def run(n_calls: int = 256, repeats: int = 5) -> tuple[list, dict]:
         "disabled_target_pct": DISABLED_GATE_PCT,
         "enabled_overhead_pct": round(pct["on"], 3),
         "enabled_target_pct": ENABLED_GATE_PCT,
-        "trace_stride": TRACE_STRIDE,
         "export_validation": "PASS",
         "verdict": overall,
     }
@@ -221,7 +233,7 @@ def main() -> None:
     # overwrites the committed full-run trajectory with tiny-n noise
     write_bench_json("smoke_obs_overhead" if args.smoke else "obs_overhead",
                      {"n_calls": n_calls, "repeats": repeats, "bs": BS,
-                      "trace_stride": TRACE_STRIDE, "smoke": args.smoke},
+                      "smoke": args.smoke},
                      rows, acceptance)
 
 

@@ -15,17 +15,23 @@ drains) anything.  The Query future is issued on the same channel, so
 FIFO order guarantees it observes every probe issued before it.
 
 This example also demonstrates the observability front door
-(docs/OBSERVABILITY.md): ``inc.obs.enable(trace=True)`` turns the
-data-plane metrics/tracing on, ``inc.metrics()`` records an
-application-level counter next to the built-in ones, ``inc.trace(...)``
-wraps the probe loop in a user span, and the run ends with the
-per-channel p99 latency from ``rt.metrics_snapshot()``.
+(docs/OBSERVABILITY.md): ``inc.obs.enable()`` turns the data-plane
+metrics and spans on, ``inc.metrics()`` records an application-level
+counter next to the built-in ones, ``inc.trace(...)`` wraps the probe
+loop in a user span, a ``jax.profiler`` capture holds that span and the
+data plane's own, and the run ends with the per-channel p99 latency
+from ``rt.metrics_snapshot()``.
 
     PYTHONPATH=src python -m examples.monitoring
 """
+import tempfile
+
 import numpy as np
 
+import jax
+
 import repro.api as inc
+from repro.obs.trace import captured_spans
 
 
 @inc.service(app="MON-1",
@@ -41,9 +47,9 @@ class Monitor:
 
 
 def main():
-    # observability on for the whole run: data-plane metrics + span
-    # tracing (every 4th coalesced batch lands on the trace timeline)
-    inc.obs.enable(trace=True, trace_stride=4)
+    # observability on for the whole run: data-plane metrics + spans
+    # (they land in the profiler capture around the probe burst)
+    inc.obs.enable()
     rt = inc.IncRuntime()
     rt.server.register("MonitorCall", lambda req: {"payload": "ack"})
     probe = rt.make_stub(Monitor, n_slots=512)
@@ -55,7 +61,11 @@ def main():
     rng = np.random.RandomState(0)
     truth = {}
     futures = []
-    with inc.trace("probe_burst", n=200):
+    trace_dir = tempfile.TemporaryDirectory()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0          # the spans, not every frame
+    with jax.profiler.trace(trace_dir.name, profiler_options=opts), \
+            inc.trace("probe_burst", n=200):
         for _ in range(200):
             flows = rng.zipf(1.4, 64) % 2000
             kvs = {}
@@ -66,9 +76,16 @@ def main():
             probes_sent.inc()
             futures.append(probe.MonitorCall(kvs=kvs, payload="probe"))
 
-    # the monitor reads at any time; the Query rides the same channel
-    # queue, so it drains behind all 200 probes (.result() demand-flushes)
-    reply = probe.Query(kvs={k: 0 for k in truth}).result()
+        # the monitor reads at any time; the Query rides the same channel
+        # queue, so it drains behind all 200 probes (.result()
+        # demand-flushes)
+        reply = probe.Query(kvs={k: 0 for k in truth}).result()
+    spans = captured_spans(trace_dir.name, prefix="")
+    trace_dir.cleanup()
+    drains = sum(nm.startswith("inc.drain") for evs in spans.values()
+                 for nm, *_ in evs)
+    user = sum(nm == "probe_burst" for evs in spans.values()
+               for nm, *_ in evs)
     assert all(f.result()["payload"] == "ack" for f in futures)
     got = {k: int(v) for k, v in reply["kvs"].items()}
     assert got == truth
@@ -90,7 +107,10 @@ def main():
           f"p50={mon.get('latency_p50_us', 0.0)}us "
           f"p99={mon.get('latency_p99_us', 0.0)}us; "
           f"CHR={snap['switch']['apps']['MON-1']['cache_hit_ratio']:.3f}; "
-          f"{len(inc.obs.tracer())} trace events recorded")
+          f"capture: {drains} inc.drain spans on "
+          f"{len([ln for ln in spans if ln.startswith('inc-drain')])} "
+          f"drain worker line(s), {user} user span")
+    assert drains >= 1 and user == 1
     print("== every counter exact (switch + host-spill fallback)")
     rt.close()
     inc.obs.disable()

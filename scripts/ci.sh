@@ -175,9 +175,10 @@ rm -f "$smoke_log"
 echo "wire quorum: OK"
 
 # obs lane: the exports users consume must hold their published shapes —
-# a live traced runtime's metrics_snapshot() validates against the
-# checked-in scripts/obs_schema.json and the Chrome trace JSON validates
-# as Perfetto-loadable; the committed full-run BENCH_obs_overhead.json
+# a live observed runtime's metrics_snapshot() validates against the
+# checked-in scripts/obs_schema.json, and a jax.profiler capture of it
+# holds every drained batch's inc.drain span with its inc.pass on a
+# drain worker's host line; the committed full-run BENCH_obs_overhead.json
 # must exist with a passing verdict (re-run `make bench-obs` when the
 # instrumentation changes).
 if ! timeout 120 python - <<'EOF'
@@ -185,23 +186,34 @@ import json
 import pathlib
 import sys
 
+import tempfile
+
+import jax
+
 import repro.api as inc
 from repro.obs import schema as obs_schema
-from repro.obs.trace import validate_chrome_trace
+from repro.obs.trace import captured_spans
 
-inc.obs.enable(trace=True)
+inc.obs.enable()
+trace_dir = tempfile.mkdtemp()
 with inc.IncRuntime(workers=2) as rt:
     from benchmarks.agg_goodput import BatchBench, _batch_requests
     stub = rt.make_stub(BatchBench, n_slots=8192)
-    futs = [stub.Push(**r) for r in _batch_requests(64)]
-    rt.drain()
-    for f in futs:
-        f.result()
+    with jax.profiler.trace(trace_dir):
+        futs = [stub.Push(**r) for r in _batch_requests(64)]
+        rt.drain()
+        for f in futs:
+            f.result()
     snap = rt.metrics_snapshot()
 obs_schema.validate(snap, obs_schema.load("scripts/obs_schema.json"))
 assert "latency_p99_us" in snap["channels"]["BB-1"], "p99 missing"
 assert "cache_hit_ratio" in snap["switch"]["apps"]["BB-1"], "CHR missing"
-validate_chrome_trace(inc.obs.chrome_trace())
+spans = captured_spans(trace_dir)
+drains = {a["seq"] for line, evs in spans.items() if line.startswith(
+    "inc-drain") for nm, _, _, a in evs if nm == "inc.drain"}
+passes = {a["seq"] for evs in spans.values() for nm, _, _, a in evs
+          if nm == "inc.pass"}
+assert drains and drains <= passes, f"capture lacks spans: {sorted(spans)}"
 inc.obs.disable()
 inc.obs.reset()
 
@@ -210,7 +222,7 @@ assert f.exists(), f"{f} missing — run `make bench-obs` and commit it"
 acc = json.loads(f.read_text())["acceptance"]
 assert acc["verdict"].startswith("PASS"), \
     f"committed obs gate verdict: {acc['verdict']}"
-print("obs lane: snapshot schema OK, chrome trace OK, "
+print("obs lane: snapshot schema OK, captured spans OK, "
       f"committed gate {acc['verdict']} "
       f"(disabled {acc['disabled_overhead_pct']}%, "
       f"enabled {acc['enabled_overhead_pct']}%)")

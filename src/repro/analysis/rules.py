@@ -39,8 +39,12 @@ _INIT_FUNCS = frozenset({"__init__", "__post_init__", "__new__"})
 
 # obs callees that are cold-path exports/controls, not per-event records
 _OBS_COLD_CALLEES = frozenset(
-    {"snapshot", "chrome_trace", "prometheus_text", "reset", "enable",
-     "disable", "enabled", "set_tracing"})
+    {"snapshot", "prometheus_text", "reset", "enable", "disable",
+     "enabled"})
+
+# the switchboard bools of repro.obs.hooks that guard a record or a span
+_OBS_HOOKS = "repro.obs.hooks"
+_GUARD_FLAGS = frozenset({"ON", "METRICS"})
 
 # modules whose obs calls must be guarded (the data-plane hot paths)
 _HOT_SUFFIXES = ("core/rpc.py", "core/runtime.py", "core/inc_map.py")
@@ -260,12 +264,33 @@ def check_l3(mod: ModuleInfo) -> list:
 # O1 — obs purity on hot paths
 
 def _mentions_guard(mod: ModuleInfo, expr, tainted: set) -> bool:
+    """``expr`` reads a switchboard bool (``_obs.ON`` / ``_obs.METRICS``)
+    or a local that carries one."""
     for sub in ast.walk(expr):
         if isinstance(sub, ast.Name) and sub.id in tainted:
             return True
         if isinstance(sub, ast.Attribute):
             chain = attr_chain(sub)
-            if chain and chain[0] in mod.obs_aliases:
+            if (chain and len(chain) == 2 and chain[1] in _GUARD_FLAGS
+                    and mod.obs_aliases.get(chain[0]) == _OBS_HOOKS):
+                return True
+    return False
+
+
+def _after_guard_exit(mod: ModuleInfo, block_owner, child,
+                      tainted: set) -> bool:
+    """``child`` follows an ``if not <guard>: ... return`` (or ``raise``)
+    in the same block, so it runs only with the guard up."""
+    for field in ("body", "orelse", "finalbody"):
+        block = getattr(block_owner, field, None)
+        if not isinstance(block, list) or child not in block:
+            continue
+        for stmt in block[:block.index(child)]:
+            if (isinstance(stmt, ast.If) and not stmt.orelse
+                    and isinstance(stmt.test, ast.UnaryOp)
+                    and isinstance(stmt.test.op, ast.Not)
+                    and _mentions_guard(mod, stmt.test.operand, tainted)
+                    and isinstance(stmt.body[-1], (ast.Return, ast.Raise))):
                 return True
     return False
 
@@ -273,8 +298,12 @@ def _mentions_guard(mod: ModuleInfo, expr, tainted: set) -> bool:
 def _guarded(mod: ModuleInfo, node, tainted: set) -> bool:
     """True when ``node`` only executes because an obs guard was taken:
     inside the body of ``if <guard>:``, the true branch of a guard IfExp,
-    or short-circuited behind a guard in ``guard and <node>``."""
+    short-circuited behind a guard in ``guard and <node>``, or after an
+    ``if not <guard>: return`` early exit."""
     for anc, child in mod.ancestors(node):
+        if isinstance(child, ast.stmt) \
+                and _after_guard_exit(mod, anc, child, tainted):
+            return True
         if isinstance(anc, ast.If) and child in anc.body \
                 and _mentions_guard(mod, anc.test, tainted):
             return True
@@ -293,8 +322,8 @@ def _guarded(mod: ModuleInfo, node, tainted: set) -> bool:
 
 
 def _tainted_names(mod: ModuleInfo, fn) -> set:
-    """Local names carrying an obs-guard value (``trc = _obs.TRACE and
-    ...``, ``ctx = _trace.maybe_start(...) if _obs.TRACE else None``, or
+    """Local names carrying an obs-guard value (``on = _obs.ON and
+    ...``, ``t0 = time.perf_counter() if _obs.METRICS else 0.0``, or
     any assignment inside a guarded branch). Fixpoint over assignments so
     ordering doesn't matter."""
     tainted: set = set()
@@ -350,7 +379,7 @@ def check_o1(mod: ModuleInfo) -> list:
             "O1", mod.path, node.lineno, mod.scope_of(node), detail,
             f"unguarded obs call '{detail}(...)' on a data-plane hot "
             f"path — gate it behind 'if _obs.METRICS:' / 'if "
-            f"_obs.TRACE:' or move it into an *_observed variant"))
+            f"_obs.ON:' or move it into an *_observed variant"))
     return out
 
 

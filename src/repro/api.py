@@ -26,9 +26,9 @@ The legacy string-keyed surface (``Service``/``Field``/``NetFilter`` +
 the schema layer compiles down to; new code should not need it.
 
 Observability (``repro.obs``, docs/OBSERVABILITY.md) rides along:
-``inc.obs.enable()`` turns the data-plane metrics/tracing on,
+``inc.obs.enable()`` turns the data-plane metrics and spans on,
 ``inc.metrics()`` is the process-wide registry for application metrics,
-and ``inc.trace("span")`` opens a user span on the exported timeline.
+and ``inc.trace("span")`` opens a user span on the profiler's timeline.
 """
 from repro import obs
 from repro.core.netfilter import NetFilter
@@ -62,10 +62,11 @@ def metrics():
 
 
 def trace(name: str, **args):
-    """User span on the exported timeline::
+    """User span on the profiler's timeline (a ``jax.profiler``
+    annotation, in any capture beside the data plane's own spans)::
 
         with inc.trace("train_step", step=i):
             ...
 
-    No-op unless tracing is on (``inc.obs.enable(trace=True)``)."""
+    No-op unless obs is on (``inc.obs.enable()``)."""
     return obs.trace_span(name, **args)

@@ -33,7 +33,9 @@ lookup per element.
 """
 from __future__ import annotations
 
+import functools
 import threading
+import time
 import zlib
 from collections import Counter
 from dataclasses import dataclass, field
@@ -422,12 +424,15 @@ class SwitchMemory:
                     seg.regs = ops.sparse_addto_host(
                         seg.regs, o,
                         quantize_stream(fvals[m], scale).astype(np.int32))
-                elif len(o) and (len(o) == 1 or bool((np.diff(o) == 1).all())):
+                    continue
+                fv = (_obs.h2d(fvals[m], "regfile.addto_f32") if _obs.ON
+                      else jnp.asarray(fvals[m]))
+                if len(o) and (len(o) == 1 or bool((np.diff(o) == 1).all())):
                     seg.regs = ops.device_addto_dense(
-                        seg.regs, int(o[0]), jnp.asarray(fvals[m]), scale)
+                        seg.regs, int(o[0]), fv, scale)
                 else:
-                    seg.regs = ops.device_addto_scatter(
-                        seg.regs, o, jnp.asarray(fvals[m]), scale)
+                    seg.regs = ops.device_addto_scatter(seg.regs, o, fv,
+                                                        scale)
 
     def read_f32(self, phys: np.ndarray, scale, need_raw: bool = False
                  ) -> tuple[jnp.ndarray, np.ndarray | None]:
@@ -450,12 +455,17 @@ class SwitchMemory:
                         seg.regs, int(o[0]), n, scale)
                     raw = None
                     if need_raw:
-                        raw = np.asarray(
-                            seg.regs[int(o[0]):int(o[0]) + n], np.int32)
+                        # the clear's copy of the registers, pulled to
+                        # the host behind the kernels queued on them
+                        regs = seg.regs[int(o[0]):int(o[0]) + n]
+                        raw = (_obs.d2h(regs, "regfile.read_raw")
+                               if _obs.ON else np.asarray(regs))
                 return vals, raw
         raw = self.get(phys)
         inv = np.float32(1.0) / np.float32(scale)
-        vals = jnp.asarray(raw.astype(np.float32) * inv)
+        host = raw.astype(np.float32) * inv
+        vals = (_obs.h2d(host, "regfile.read_f32") if _obs.ON
+                else jnp.asarray(host))
         return vals, (raw if need_raw else None)
 
     def get(self, phys: np.ndarray) -> np.ndarray:
@@ -472,7 +482,9 @@ class SwitchMemory:
         for s, m in self._seg_groups(seg_ix):
             seg = self.segments[s]
             with seg.lock:
-                out[m] = np.asarray(seg.regs)[off[m]]
+                regs = (_obs.d2h(seg.regs, "regfile.get") if _obs.ON
+                        else np.asarray(seg.regs))
+                out[m] = regs[off[m]]
         return out
 
     def clear(self, phys: np.ndarray) -> None:
@@ -488,16 +500,45 @@ class SwitchMemory:
                     seg.regs = seg.regs.at[jnp.asarray(off[m])].set(0)
 
 
-def _locked(fn):
+def _locked(fn=None, *, op: str | None = None):
     """Run an agent data-path method under the instance's re-entrant
-    ``lock`` (one acquire per *batch* call, not per element)."""
-    import functools
+    ``lock`` (one acquire per *batch* call, not per element). A
+    register-file verb names its ``op``: with obs on it runs in an
+    ``inc.regfile.<op>`` span and its host time is recorded under
+    ``inc_regfile_us{op}``; a verb called inside another is counted
+    there, not again."""
+    if fn is None:
+        return functools.partial(_locked, op=op)
+    if op is None:
+        @functools.wraps(fn)
+        def wrapper(self, *a, **kw):
+            with self.lock:
+                return fn(self, *a, **kw)
+        return wrapper
+    name = "inc.regfile." + op
 
     @functools.wraps(fn)
-    def wrapper(self, *a, **kw):
+    def verb(self, *a, **kw):
+        if _obs.ON:
+            return _verb_observed(op, name, fn, self, a, kw)
         with self.lock:
             return fn(self, *a, **kw)
-    return wrapper
+    return verb
+
+
+def _verb_observed(op: str, name: str, fn, agent, a, kw):
+    with agent.lock:
+        if agent._in_verb:
+            return fn(agent, *a, **kw)
+        agent._in_verb = True
+        t0 = time.perf_counter()
+        try:
+            with _trace.span(name):
+                return fn(agent, *a, **kw)
+        finally:
+            agent._in_verb = False
+            if _obs.METRICS:
+                _obs.regfile_op(op, t0)
 
 
 class ServerAgent:
@@ -529,6 +570,7 @@ class ServerAgent:
         # a drain running concurrently on another thread. Re-entrant:
         # data-path methods call each other (read -> read_batch).
         self.lock = threading.RLock()
+        self._in_verb = False       # inside a timed verb (obs on)
         self.granted = switch.reserve(gaid, n_slots, device=device)
         self.base, self.capacity = (switch.partitions.get(gaid, (0, 0)))
         self.mapping: dict[int, int] = _VersionedDict()  # logical -> physical
@@ -665,7 +707,7 @@ class ServerAgent:
 
     # -- data path ------------------------------------------------------
 
-    @_locked
+    @_locked(op="addto")
     def addto_batch(self, logical: np.ndarray, vals: np.ndarray) -> None:
         """Route a batch of (logical addr, value) updates: INC or host.
         Fully vectorized: one mapping lookup, one switch kernel batch for
@@ -676,7 +718,6 @@ class ServerAgent:
         n = len(logical)
         if n == 0:
             return
-        t0_us = _trace.now_us() if _obs.TRACE else 0.0
         q = logical.astype(np.int64)
         hit, slotv = self._map_lookup(q)
         n_hit = int(hit.sum())
@@ -694,8 +735,6 @@ class ServerAgent:
             miss = ~hit
             self._route_miss(logical[miss], vals[miss])
         self._account(logical, n)
-        if _obs.TRACE:
-            _obs.switch_op("addto", n, t0_us)
 
     def _route_miss(self, lmiss: np.ndarray, vmiss: np.ndarray) -> None:
         """Fold missed (logical, value) updates into the host spill and
@@ -722,7 +761,7 @@ class ServerAgent:
             self.end_window()
         self._flush_migrations()
 
-    @_locked
+    @_locked(op="addto_f32")
     def addto_batch_f32(self, logical: np.ndarray, fvals: np.ndarray,
                         scale) -> None:
         """The device-resident transmit lane: route an fp32 update stream
@@ -740,7 +779,6 @@ class ServerAgent:
         n = len(logical)
         if n == 0:
             return
-        t0_us = _trace.now_us() if _obs.TRACE else 0.0
         q = logical.astype(np.int64)
         hit, slotv = self._map_lookup(q)
         n_hit = int(hit.sum())
@@ -757,10 +795,8 @@ class ServerAgent:
             self._route_miss(logical[miss],
                              quantize_stream(fvals[miss], scale))
         self._account(logical, n)
-        if _obs.TRACE:
-            _obs.switch_op("addto_f32", n, t0_us)
 
-    @_locked
+    @_locked(op="read_dev")
     def read_batch_dev(self, logical: np.ndarray, scale,
                        need_raw: bool = False
                        ) -> tuple[jnp.ndarray, np.ndarray | None]:
@@ -776,7 +812,6 @@ class ServerAgent:
         if n == 0:
             raw = np.zeros(0, np.int64) if need_raw else None
             return jnp.zeros(0, jnp.float32), raw
-        t0_us = _trace.now_us() if _obs.TRACE else 0.0
         q = logical.astype(np.int64)
         spill_hit = False
         if self.spill:
@@ -789,12 +824,12 @@ class ServerAgent:
                 vals, raw32 = self.switch.read_f32(
                     self.base + slotv, scale, need_raw=need_raw)
                 raw = raw32.astype(np.int64) if need_raw else None
-                if _obs.TRACE:
-                    _obs.switch_op("read_dev", n, t0_us)
                 return vals, raw
         raw = self.read_batch(logical)
         inv = np.float32(1.0) / np.float32(scale)
-        vals = jnp.asarray(raw.astype(np.float32) * inv)
+        host = raw.astype(np.float32) * inv
+        vals = (_obs.h2d(host, "regfile.read_dev") if _obs.ON
+                else jnp.asarray(host))
         return vals, (raw if need_raw else None)
 
     @_locked
@@ -817,7 +852,7 @@ class ServerAgent:
         """Map.get: switch register (if mapped) + host spill."""
         return int(self.read_batch(np.array([logical], np.uint32))[0])
 
-    @_locked
+    @_locked(op="read")
     def read_batch(self, logical: np.ndarray) -> np.ndarray:
         """Batched Map.get: ONE switch gather for all mapped addresses plus
         the host-spill components — the data-plane read of call_batch.
@@ -828,7 +863,6 @@ class ServerAgent:
         out = np.zeros(n, np.int64)
         if n == 0:
             return out
-        t0_us = _trace.now_us() if _obs.TRACE else 0.0
         q = logical.astype(np.int64)
         if self.spill:
             skeys, svals = self._spill_arrays()
@@ -841,8 +875,6 @@ class ServerAgent:
             if hit.any():
                 out[hit] += self.switch.get(
                     self.base + slotv[hit]).astype(np.int64)
-        if _obs.TRACE:
-            _obs.switch_op("read", n, t0_us)
         return out
 
     @_locked

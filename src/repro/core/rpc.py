@@ -223,7 +223,8 @@ def _stream_items(request: dict, msg_field: str) -> "dict | TensorSegment":
         return {}
     if isinstance(v, dict):
         return v
-    arr = np.asarray(v)
+    # a device array is pulled to the host here
+    arr = _obs.d2h(v, "rpc.stream_items") if _obs.ON else np.asarray(v)
     if _GPV[0] and arr.ndim >= 1 and arr.dtype.kind in "biuf":
         return TensorSegment(data=arr.reshape(-1), shape=arr.shape)
     return {i: x for i, x in enumerate(arr.ravel())}
@@ -366,7 +367,7 @@ def _run_pipeline(channel: Channel, host_server: Server,
     runtime-coalesced ("drained") counters so coalescing efficiency is not
     diluted by interleaved N=1 Stub.call passes on the same channel.
     """
-    if not (_obs.METRICS or _obs.TRACE):
+    if not _obs.ON:
         # the zero-overhead default: one module-global bool load + branch,
         # then exactly the pre-obs pass
         if not channel.plane.acquire(timeout=PLANE_LOCK_TIMEOUT):
@@ -393,42 +394,64 @@ def _run_pipeline_observed(channel: Channel, host_server: Server,
                            source: str) -> list[dict]:
     """Instrumented twin of the fast path in ``_run_pipeline``: same lock
     discipline and error semantics, plus plane-lock-wait / pass-duration /
-    GPV-coverage metrics and a sampled batch span. If the runtime's drain
-    worker already opened a batch span, ``maybe_start`` returns None and
-    the phase markers below join the enclosing span's context."""
+    GPV-coverage metrics and the pass's spans. The ``inc.pass`` span takes
+    the number of the drain batch that runs it (repro/obs/trace.py)."""
     app = channel.netfilter.app_name
-    ctx = _trace.maybe_start("pipeline", app, n=len(calls),
-                             source=source) if _obs.TRACE else None
-    t_wait = time.perf_counter()
-    acquired = channel.plane.acquire(timeout=PLANE_LOCK_TIMEOUT)
-    t0 = time.perf_counter()
-    if not acquired:
-        _trace.end(ctx)
-        raise _plane_lock_timeout(channel)
-    try:
-        if ctx is not None:
-            _trace.phase("plane_lock", t_wait * 1e6)
-        gpv_c0 = channel.stats.gpv_calls
-        gpv_e0 = channel.stats.gpv_elems
+    with _trace.pass_span("inc.pass", app=app, n=len(calls), source=source):
+        t_wait = time.perf_counter()
+        with _trace.span("inc.plane_lock"):
+            acquired = channel.plane.acquire(timeout=PLANE_LOCK_TIMEOUT)
+        t0 = time.perf_counter()
+        if not acquired:
+            raise _plane_lock_timeout(channel)
         try:
-            return _run_pipeline_locked(channel, host_server, calls, source)
+            gpv_c0 = channel.stats.gpv_calls
+            gpv_e0 = channel.stats.gpv_elems
+            try:
+                return _run_pipeline_locked_observed(channel, host_server,
+                                                     calls, source)
+            finally:
+                if _obs.METRICS:
+                    _obs.plane_wait(app, (t0 - t_wait) * 1e6)
+                    _obs.pipeline_pass(app, len(calls), source, t0)
+                    dg = channel.stats.gpv_calls - gpv_c0
+                    _obs.gpv_coverage(app, dg,
+                                      channel.stats.gpv_elems - gpv_e0,
+                                      len(calls) - dg)
         finally:
-            if _obs.METRICS:
-                _obs.plane_wait(app, (t0 - t_wait) * 1e6)
-                _obs.pipeline_pass(app, len(calls), source, t0)
-                dg = channel.stats.gpv_calls - gpv_c0
-                _obs.gpv_coverage(app, dg,
-                                  channel.stats.gpv_elems - gpv_e0,
-                                  len(calls) - dg)
-    finally:
-        channel.plane.release()
-        _trace.end(ctx)
+            channel.plane.release()
 
 
 def _run_pipeline_locked(channel: Channel, host_server: Server,
                          calls: list[_PlannedCall],
                          source: str) -> list[dict]:
-    server = channel.server
+    _open_pass(channel, calls, source)
+    _phase_stream_modify(channel, calls)
+    _phase_resolve_addrs(calls)
+    _phase_cntfwd_gate(channel.server, calls)
+    _phase_execute(channel, host_server, calls)
+    return [c.reply for c in calls]
+
+
+def _run_pipeline_locked_observed(channel: Channel, host_server: Server,
+                                  calls: list[_PlannedCall],
+                                  source: str) -> list[dict]:
+    """``_run_pipeline_locked`` with each phase in its span."""
+    _open_pass(channel, calls, source)
+    with _trace.span("inc.stream_modify"):
+        _phase_stream_modify(channel, calls)
+    with _trace.span("inc.resolve_addrs"):
+        _phase_resolve_addrs(calls)
+    with _trace.span("inc.cntfwd_gate"):
+        _phase_cntfwd_gate(channel.server, calls)
+    with _trace.span("inc.execute"):
+        _phase_execute(channel, host_server, calls)
+    return [c.reply for c in calls]
+
+
+def _open_pass(channel: Channel, calls: list[_PlannedCall],
+               source: str) -> None:
+    """Flush an enclosing pass's buffer and account the pass."""
     if channel.active_buf is not None:
         # nested pass (a handler's inline follow-up call on its own
         # channel): the enclosing pass's buffered updates — including
@@ -445,13 +468,12 @@ def _run_pipeline_locked(channel: Channel, host_server: Server,
     else:
         channel.stats.explicit_calls += len(calls)
         channel.stats.explicit_batches += 1
-    # per-phase spans land on the sampled batch context (if any); ``trc``
-    # short-circuits on the module-global bool so the disabled path pays
-    # one load + branch here and a falsy local check per phase
-    trc = _obs.TRACE and _trace.current() is not None
-    t_ph = _trace.now_us() if trc else 0.0
 
-    # ---- phase 1: Stream.modify, fused across the batch --------------------
+
+def _phase_stream_modify(channel: Channel,
+                         calls: list[_PlannedCall]) -> None:
+    """Phase 1: the request-to-stream conversion, and Stream.modify fused
+    across the batch."""
     for c in calls:
         if c.prefolded:
             # locally folded flush (Agg[...](local_accum=N)): items were
@@ -515,11 +537,10 @@ def _run_pipeline_locked(channel: Channel, host_server: Server,
                 s = 10 ** c.nf.precision
                 c.items = dict(zip(c.items.keys(), out / s))
             pos += len(seg)
-    if trc:
-        _trace.phase("stream_modify", t_ph)
-        t_ph = _trace.now_us()
 
-    # ---- phase 2: client-side logical-address resolution --------------------
+
+def _phase_resolve_addrs(calls: list[_PlannedCall]) -> None:
+    """Phase 2: client-side logical-address resolution."""
     for c in calls:
         if c.items:
             if isinstance(c.items, TensorSegment):
@@ -540,11 +561,10 @@ def _run_pipeline_locked(channel: Channel, host_server: Server,
             else:
                 c.logs, c.vals, c.spills = c.agent.resolve(c.items,
                                                            c.nf.precision)
-    if trc:
-        _trace.phase("resolve_addrs", t_ph)
-        t_ph = _trace.now_us()
 
-    # ---- phase 3: CntFwd gating (simulated over pre-batch counters) ---------
+
+def _phase_cntfwd_gate(server, calls: list[_PlannedCall]) -> None:
+    """Phase 3: CntFwd gating, simulated over pre-batch counters."""
     # Counter keys are disjoint from data keys, so the per-tag count at any
     # point in the batch is the pre-batch value plus the in-batch increments
     # before it — no device round trip per call. The actual counter writes
@@ -571,14 +591,15 @@ def _run_pipeline_locked(channel: Channel, host_server: Server,
             if c.forwarded and c.nf.clear != "nop":
                 c.counter_ops.append((key, -cnt))
                 sim[key] = 0
-    if trc:
-        _trace.phase("cntfwd_gate", t_ph)
-        t_ph = _trace.now_us()
 
-    # ---- phase 4: ordered execution with lazy flushing ----------------------
+
+def _phase_execute(channel: Channel, host_server: Server,
+                   calls: list[_PlannedCall]) -> None:
+    """Phase 4: ordered execution with lazy flushing."""
     # The final flush runs even if a handler raises mid-batch, so calls that
     # already took their turn keep their INC side effects — exactly as if
     # they had been issued sequentially before the failing call.
+    server = channel.server
     buf = _MapOpBuffer(server)
     prev_buf = channel.active_buf          # enclosing pass when nested
     channel.active_buf = buf
@@ -669,9 +690,6 @@ def _run_pipeline_locked(channel: Channel, host_server: Server,
     finally:
         channel.active_buf = prev_buf
         buf.flush()
-        if trc:
-            _trace.phase("execute", t_ph)
-    return [c.reply for c in calls]
 
 
 # -- client stub -------------------------------------------------------------
@@ -1028,8 +1046,9 @@ class _FoldBuffer:
         nf = self.md.netfilter
         scale = 10 ** nf.precision
         if self.mode == "dev" and self.frounds:
-            qsum = np.asarray(device_fold_rounds(self.frounds, scale),
-                              np.int64)
+            qdev = device_fold_rounds(self.frounds, scale)
+            qsum = (_obs.d2h(qdev, "rpc.fold") if _obs.ON
+                    else np.asarray(qdev)).astype(np.int64)
             items = TensorSegment(data=np.zeros(len(qsum), np.float32),
                                   shape=self.shape, qvals=qsum)
         elif self.mode == "tensor" and self.qrounds:

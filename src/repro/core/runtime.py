@@ -66,6 +66,7 @@ drain counts and queue-wait percentiles, and the pick-contention count.
 """
 from __future__ import annotations
 
+import ctypes
 import threading
 import time
 from collections import deque
@@ -79,6 +80,18 @@ from repro.obs import hooks as _obs
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.obs.metrics import Histogram
+
+_PR_SET_NAME = 15           # prctl option: name the calling thread
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread ``name`` at the OS level too (Linux keeps
+    15 bytes), so a profiler capture shows it on a host line of its own
+    name rather than under the process's."""
+    try:
+        ctypes.CDLL(None).prctl(_PR_SET_NAME, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass
 
 
 @dataclass
@@ -821,6 +834,7 @@ class IncRuntime(NetRPC):
 
     def _loop(self, wid: int) -> None:
         self._tls.worker = True
+        _name_os_thread(threading.current_thread().name)
         stats = self._worker_stats[wid]
         while True:
             # the fold staleness sweep runs outside _work (its dispatches
@@ -878,21 +892,16 @@ class IncRuntime(NetRPC):
         pipeline exception (already delivered to futures) or None. Runs
         under the channel's plane lock (acquired inside _run_pipeline), so
         passes for other channels proceed concurrently."""
+        if _obs.ON:
+            with _trace.batch("inc.drain", app=q.channel.netfilter.app_name,
+                              n=len(entries), trigger=trigger):
+                return self._execute_batch(q, entries, trigger)
+        return self._execute_batch(q, entries, trigger)
+
+    def _execute_batch(self, q: _ChannelQueue, entries, trigger: str):
         ch = q.channel
         exc = None
         t_start = self._clock()
-        ctx = None
-        t_drain_us = 0.0
-        if _obs.TRACE:
-            app = ch.netfilter.app_name
-            ctx = _trace.maybe_start("drain", app, n=len(entries),
-                                     trigger=trigger)
-            if ctx is not None:
-                # the queue-side story on the channel's synthetic track:
-                # a "queued" span ending now, then the drain span below
-                _trace.queued_event(app, t_start - entries[0][2],
-                                    len(entries), trigger)
-                t_drain_us = _trace.now_us()
         try:
             self._run_plane(lambda: _run_pipeline(
                 ch, self.server, [p for _, p, _ in entries],
@@ -925,14 +934,17 @@ class IncRuntime(NetRPC):
             q.h_wait.observe(max(0.0, t_start - entries[0][2]) * 1e6)
             q.h_lat.observe_many(
                 [(t_done - ts) * 1e6 for _, _, ts in entries])
+            _obs.queue_wait(app, [max(0.0, t_start - ts) * 1e6
+                                  for _, _, ts in entries])
             _obs.drain_trigger(app, trigger)
             _obs.aimd_update(app, q.aimd.cw, ecn)
         # the worker loop deliberately swallows the return value, so
         # the outcome (including a trailing-flush failure, charged to the
         # last call) must be fully delivered through the futures
-        resolve_futures([(fut, p) for fut, p, _ in entries], exc)
-        if ctx is not None:
-            _trace.drain_event(ch.netfilter.app_name, t_drain_us,
-                               len(entries), trigger)
-            _trace.end(ctx)
+        pairs = [(fut, p) for fut, p, _ in entries]
+        if _obs.ON:
+            with _trace.span("inc.resolve", n=len(pairs)):
+                resolve_futures(pairs, exc)
+        else:
+            resolve_futures(pairs, exc)
         return exc

@@ -351,8 +351,9 @@ def device_fold_rounds(frounds: list, scale) -> jax.Array:
     ``device=True`` lane of ``local_accum``. Returns the folded int32
     stream; agrees with :func:`fold_rounds` over host-quantized rounds
     wherever no intermediate sum saturates."""
-    fstack = jnp.stack([jnp.asarray(f, jnp.float32).reshape(-1)
-                        for f in frounds])
+    fstack = jnp.stack([
+        (_obs.h2d(f, "rpc.fold", jnp.float32) if _obs.ON
+         else jnp.asarray(f, jnp.float32)).reshape(-1) for f in frounds])
     return _launch("fused_fold", int(fstack.size), _fused_fold_jit, fstack,
                    jnp.asarray(scale, jnp.float32))
 
@@ -404,9 +405,11 @@ def device_addto_scatter(regs, idx, fvals, scale) -> jax.Array:
     k = int(idx.shape[0])
     if k == 0:
         return regs
+    idx = (_obs.h2d(idx, "regfile.addto_scatter", jnp.int32) if _obs.ON
+           else jnp.asarray(idx, jnp.int32))
     bucket = 1 << (k - 1).bit_length()
     if bucket != k:
-        idx = jnp.pad(jnp.asarray(idx, jnp.int32), (0, bucket - k))
+        idx = jnp.pad(idx, (0, bucket - k))
         fvals = jnp.pad(jnp.asarray(fvals, jnp.float32), (0, bucket - k))
     return _launch("fused_scatter", k, _fused_scatter_jit, regs, idx,
                    fvals, scale)
@@ -421,12 +424,18 @@ def device_addto_int(regs, idx, vals) -> jax.Array:
     k = int(idx.shape[0])
     if k == 0:
         return regs
+    if _obs.ON:
+        idx = _obs.h2d(idx, "regfile.addto_int", jnp.int32)
+        vals = _obs.h2d(vals, "regfile.addto_int", jnp.int32)
+    else:
+        idx = jnp.asarray(idx, jnp.int32)
+        vals = jnp.asarray(vals, jnp.int32)
     bucket = 1 << (k - 1).bit_length()
     if bucket != k:
-        idx = jnp.pad(jnp.asarray(idx, jnp.int32), (0, bucket - k))
-        vals = jnp.pad(jnp.asarray(vals, jnp.int32), (0, bucket - k))
-    return _launch("sparse_addto", k, _device_scatter_int_jit, regs,
-                   jnp.asarray(idx, jnp.int32), jnp.asarray(vals, jnp.int32))
+        idx = jnp.pad(idx, (0, bucket - k))
+        vals = jnp.pad(vals, (0, bucket - k))
+    return _launch("sparse_addto", k, _device_scatter_int_jit, regs, idx,
+                   vals)
 
 
 def device_read_dense(regs, start: int, n: int, scale

@@ -1,40 +1,41 @@
-"""repro.obs — end-to-end observability for the INC data plane (ISSUE 7).
+"""repro.obs — end-to-end observability for the INC data plane.
 
 One import gives three things:
 
   metrics   a lock-striped registry of counters / gauges / fixed-bucket
             histograms with a no-op-when-disabled fast path
             (repro/obs/metrics.py). The data plane records per-channel
-            submit→resolve latency, drain-trigger mix, AIMD cw / ECN
-            marks, switch hit/miss/spill, GPV coverage, and Pallas
+            submit→resolve latency and queue wait, drain-trigger mix,
+            AIMD cw / ECN marks, switch hit/miss/spill, GPV coverage,
+            host↔device copies, register-file host time and Pallas
             kernel launch timings into it — but ONLY while enabled.
-  tracing   span tracing of pipeline batches into a bounded ring buffer,
-            exportable as Chrome trace-event JSON (Perfetto-loadable);
-            deterministic every-stride-th-batch sampling
-            (repro/obs/trace.py).
+  spans     the served path's layers as ``jax.profiler`` annotations
+            (``inc.drain``, ``inc.pass``, its phases, ``inc.regfile.*``,
+            ``inc.xfer.*``), on the device trace's clock in any profiler
+            capture (repro/obs/trace.py).
   export    ``IncRuntime.metrics_snapshot()`` (stable schema
-            ``repro.obs/v1``, validated by scripts/obs_schema.json),
-            ``registry().prometheus_text()``, and
-            ``chrome_trace()``/``write_trace()``.
+            ``repro.obs/v1``, validated by scripts/obs_schema.json) and
+            ``registry().prometheus_text()``.
 
 Everything is OFF by default: the instrumented hot paths compile down to
-one module-global bool branch per batch (repro/obs/hooks.py), so the
+one module-global bool branch per site (repro/obs/hooks.py), so the
 pre-obs data plane is the disabled mode. Turn it on with::
 
     from repro import obs
-    obs.enable(trace=True, trace_stride=16)
-    ... workload ...
+    obs.enable()                       # metrics and spans
+    with jax.profiler.trace("/tmp/inc-trace"):
+        ... workload ...
     snap = rt.metrics_snapshot()
-    obs.write_trace("trace.json")
     obs.disable()
 
-or set ``REPRO_OBS=1`` in the environment (metrics only).
-``benchmarks/obs_overhead.py`` (make bench-obs) pins disabled-mode
-overhead ≤2% and sampled-enabled overhead ≤10% on the agg_goodput hot
-path.
+or set ``REPRO_OBS=1`` in the environment. ``enable(metrics=False)``
+opens the spans alone. ``benchmarks/obs_overhead.py`` (make bench-obs)
+pins disabled-mode overhead ≤2% and enabled overhead ≤10% on the
+agg_goodput hot path.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 
 from repro.obs import hooks as _hooks
@@ -43,14 +44,14 @@ from repro.obs import schema
 from repro.obs import trace as _trace
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                SCHEMA_VERSION)
-from repro.obs.trace import TraceRecorder, validate_chrome_trace
 
 __all__ = [
     "SCHEMA_VERSION", "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "TraceRecorder", "registry", "tracer", "enable", "disable", "enabled",
-    "metrics_enabled", "tracing_enabled", "trace_span", "chrome_trace",
-    "write_trace", "reset", "validate_chrome_trace", "schema",
+    "registry", "enable", "disable", "enabled", "metrics_enabled",
+    "trace_span", "reset", "schema",
 ]
+
+_NULL_SPAN = contextlib.nullcontext()
 
 
 def registry() -> MetricsRegistry:
@@ -60,41 +61,27 @@ def registry() -> MetricsRegistry:
     return _metrics.REGISTRY
 
 
-def tracer() -> TraceRecorder:
-    return _trace.TRACER
-
-
-def enable(metrics: bool = True, trace: bool = False,
-           trace_stride: int = 1, trace_capacity: int | None = None
-           ) -> None:
-    """Turn observability on. ``metrics`` enables the registry;
-    ``trace`` enables span tracing, sampling every ``trace_stride``-th
-    batch into a ring of ``trace_capacity`` events."""
+def enable(metrics: bool = True) -> None:
+    """Turn observability on: the data plane's spans always, the
+    metrics registry too unless ``metrics`` is False."""
     _metrics.REGISTRY.enabled = bool(metrics)
-    _trace.set_tracing(bool(trace), stride=trace_stride,
-                       capacity=trace_capacity)
-    _hooks.sync()
+    _hooks.sync(True)
 
 
 def disable() -> None:
     """Back to the zero-overhead default: the data-plane call sites fall
-    through their single-bool guards again. Recorded metrics and trace
-    events are retained (use ``reset()`` to drop them)."""
+    through their single-bool guards again. Recorded metrics are
+    retained (use ``reset()`` to drop them)."""
     _metrics.REGISTRY.enabled = False
-    _trace.set_tracing(False)
-    _hooks.sync()
+    _hooks.sync(False)
 
 
 def enabled() -> bool:
-    return _hooks.METRICS or _hooks.TRACE
+    return _hooks.ON
 
 
 def metrics_enabled() -> bool:
     return _hooks.METRICS
-
-
-def tracing_enabled() -> bool:
-    return _hooks.TRACE
 
 
 def trace_span(name: str, **args):
@@ -103,25 +90,16 @@ def trace_span(name: str, **args):
         with inc.trace("train_step", step=i):
             ...
 
-    Records on the calling thread's track while tracing is enabled;
-    a no-op context manager otherwise."""
-    return _trace.user_span(name, **args)
-
-
-def chrome_trace() -> dict:
-    return _trace.TRACER.chrome_trace()
-
-
-def write_trace(path) -> None:
-    """Dump the trace ring as Chrome trace-event JSON (open the file in
-    Perfetto via ui.perfetto.dev > "Open trace file")."""
-    _trace.TRACER.write(path)
+    A ``jax.profiler`` annotation on the calling thread while obs is
+    enabled; a no-op context manager otherwise."""
+    if not _hooks.ON:
+        return _NULL_SPAN
+    return _trace.span(name, **args)
 
 
 def reset() -> None:
-    """Drop recorded metrics and trace events (bench legs / tests)."""
+    """Drop recorded metrics (bench legs / tests)."""
     _metrics.REGISTRY.reset()
-    _trace.TRACER.clear()
 
 
 if os.environ.get("REPRO_OBS") == "1":

@@ -1,8 +1,10 @@
 """repro.obs.hooks — the switchboard the instrumented hot paths read.
 
-``METRICS`` and ``TRACE`` are plain module globals, flipped only by
-``repro.obs.enable()/disable()``. Every data-plane call site guards its
-instrumentation with::
+``ON`` and ``METRICS`` are plain module globals, flipped only by
+``repro.obs.enable()/disable()``: ``ON`` while obs is enabled at all (the
+data plane then opens its spans, repro/obs/trace.py), ``METRICS`` while
+the registry records too. Every data-plane call site guards its
+instrumentation with one of them::
 
     if _obs.METRICS:
         t0 = time.perf_counter()
@@ -10,26 +12,32 @@ instrumentation with::
     if _obs.METRICS:
         _obs.pipeline_pass(app, n, source, t0)
 
+    out = _obs.d2h(x, "regfile.read_raw") if _obs.ON else np.asarray(x)
+
 so the *disabled* build — the default — pays exactly one module-global
-load + bool branch per pipeline batch per site: there is no hook object
-to call, no registry lookup, no lock. That is the structural entirety of
+load + bool branch per site: there is no hook object to call, no registry
+lookup, no lock, no span. That is the structural entirety of
 disabled-mode overhead, and benchmarks/obs_overhead.py pins it ≤2% on
 the agg_goodput hot path (empirically indistinguishable from noise).
 
 The record functions below run only when obs is enabled; they are
-batch-granular (one histogram observe / counter inc per pipeline pass or
-kernel launch, never per element), so sampled-enabled mode stays within
+batch-granular (one histogram observe / counter inc per pipeline pass,
+copy or kernel launch, never per element), so enabled mode stays within
 the ≤10% gate.
 """
 from __future__ import annotations
 
 import time
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 
+ON = False
 METRICS = False
-TRACE = False
 
 # pipeline-pass durations are typically tens of us to tens of ms;
 # element-count instruments use the power-of-two buckets
@@ -37,12 +45,12 @@ _US = _metrics.LATENCY_BUCKETS_US
 _N = _metrics.COUNT_BUCKETS
 
 
-def sync() -> None:
-    """Mirror the registry/tracer enable state into the call-site bools
-    (called by repro.obs.enable/disable)."""
-    global METRICS, TRACE
-    METRICS = _metrics.REGISTRY.enabled
-    TRACE = _trace.enabled()
+def sync(on: bool) -> None:
+    """Mirror the enable state into the call-site bools (called by
+    repro.obs.enable/disable)."""
+    global ON, METRICS
+    ON = bool(on)
+    METRICS = ON and _metrics.REGISTRY.enabled
 
 
 # -- record functions (enabled mode only) -----------------------------------
@@ -111,10 +119,63 @@ def kernel_launch(kernel: str, n: int, t0: float) -> None:
     reg.counter("inc_kernel_elems_total", kernel=kernel).inc(n)
 
 
-def switch_op(op: str, n: int, t0_us: float) -> None:
-    """Switch addto/read span on the active trace context (no-op when the
-    batch was not sampled)."""
-    _trace.phase(f"switch_{op}", t0_us, n=n)
+def queue_wait(app: str, waits_us: list[float]) -> None:
+    """Every call of one drained batch: submit-to-pick wait."""
+    _metrics.REGISTRY.histogram("inc_queue_wait_us", buckets=_US,
+                                app=app).observe_many(waits_us)
+
+
+def regfile_op(op: str, t0: float) -> None:
+    """Host time of one register-file data-path verb (ServerAgent)."""
+    _metrics.REGISTRY.histogram("inc_regfile_us", buckets=_US,
+                                op=op).observe(
+        (time.perf_counter() - t0) * 1e6)
+
+
+# -- host<->device copies ----------------------------------------------------
+#
+# Array buffers that cross the host link in the served path, recorded at
+# the site that moves them: an ``inc.xfer.<dir>`` span carrying ``site``
+# and ``bytes``, and with metrics on ``inc_xfer_bytes_total{dir,site}``
+# and ``inc_xfer_us{dir,site}``. Sites inside the register file are named
+# ``regfile.*``. Scalar jit arguments are not counted.
+
+def _xfer(direction: str, site: str, nbytes: int, t0: float) -> None:
+    reg = _metrics.REGISTRY
+    reg.counter("inc_xfer_bytes_total", dir=direction, site=site).inc(nbytes)
+    reg.histogram("inc_xfer_us", buckets=_US, dir=direction,
+                  site=site).observe((time.perf_counter() - t0) * 1e6)
+
+
+def d2h(x, site: str) -> np.ndarray:
+    """``np.asarray(x)``, recorded as one device-to-host copy. A value
+    that crosses no link is not recorded: a host array, or a jax array
+    whose host copy jax kept from an earlier pull."""
+    if not isinstance(x, jax.Array) \
+            or getattr(x, "_npy_value", None) is not None:
+        return np.asarray(x)
+    nbytes = int(x.nbytes)
+    t0 = time.perf_counter()
+    with _trace.span("inc.xfer.d2h", site=site, bytes=nbytes):
+        out = np.asarray(x)
+    if METRICS:
+        _xfer("d2h", site, nbytes, t0)
+    return out
+
+
+def h2d(x, site: str, dtype=None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``, recorded as one host-to-device copy; a
+    jax array is passed through unrecorded."""
+    if isinstance(x, jax.Array):
+        return jnp.asarray(x, dtype)
+    x = np.asarray(x, dtype)
+    nbytes = x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize
+    t0 = time.perf_counter()
+    with _trace.span("inc.xfer.h2d", site=site, bytes=nbytes):
+        out = jnp.asarray(x)
+    if METRICS:
+        _xfer("h2d", site, nbytes, t0)
+    return out
 
 
 # -- real-wire transport (repro.net) -----------------------------------------

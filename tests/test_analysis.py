@@ -236,10 +236,14 @@ def test_o1_quiet_when_guarded():
     from repro.obs import hooks as _obs
     from repro.obs import trace as _trace
     def step(x):
-        t0 = _trace.now_us() if _obs.TRACE else 0.0
+        t0 = time.perf_counter() if _obs.METRICS else 0.0
         if _obs.METRICS:
             _obs.kernel_launch("k", 1, t0)
-        return x
+        y = _obs.d2h(x, "site") if _obs.ON else np.asarray(x)
+        if _obs.ON:
+            with _trace.span("inc.step"):
+                y = y + 1
+        return y
     """
     assert not findings(src, "O1", path=HOT)
 
@@ -249,12 +253,12 @@ def test_o1_tracks_guard_variables_and_boolops():
     from repro.obs import hooks as _obs
     from repro.obs import trace as _trace
     def step(x):
-        trc = _obs.TRACE and _trace.current() is not None
-        if trc:
-            _trace.phase("p", 0.0)
-        ctx = _trace.maybe_start("s", "app") if _obs.TRACE else None
-        if ctx is not None:
-            _trace.end(ctx)
+        on = _obs.ON and x is not None
+        if on:
+            _trace.span("p")
+        ann = _trace.batch("s", app="app") if _obs.ON else None
+        if ann is not None:
+            _trace.span("q")
         return x
     """
     assert not findings(src, "O1", path=HOT)
@@ -264,7 +268,7 @@ def test_o1_exempts_observed_variants():
     src = """
     from repro.obs import trace as _trace
     def _run_pipeline_observed(x):
-        _trace.phase("plane_lock", 0.0)
+        _trace.span("inc.plane_lock")
         return x
     """
     assert not findings(src, "O1", path=HOT)
@@ -275,12 +279,44 @@ def test_o1_fires_outside_guard_branch():
     from repro.obs import hooks as _obs
     from repro.obs import trace as _trace
     def step(x):
-        if _obs.TRACE:
+        if _obs.ON:
             pass
-        _trace.phase("p", 0.0)
+        _trace.span("p")
         return x
     """
     assert len(findings(src, "O1", path=HOT)) == 1
+
+
+def test_o1_accepts_guard_early_exit():
+    src = """
+    from repro.obs import hooks as _obs
+    from repro.obs import trace as _trace
+    def step(x):
+        if not _obs.ON:
+            return x
+        with _trace.span("inc.step"):
+            return x + 1
+    """
+    assert not findings(src, "O1", path=HOT)
+    # an early exit on some other condition guards nothing
+    assert len(findings(src.replace("not _obs.ON", "not x"), "O1",
+                        path=HOT)) == 1
+
+
+def test_o1_guard_is_a_switchboard_bool():
+    # any other attribute of an obs module is no guard: only the hooks'
+    # ON / METRICS bools compile the off path down to one branch
+    src = """
+    from repro.obs import hooks as _obs
+    from repro.obs import trace as _trace
+    def step(x):
+        if _obs.enabled_somehow:
+            _trace.span("p")
+        if _trace.pending:
+            _trace.span("q")
+        return x
+    """
+    assert len(findings(src, "O1", path=HOT)) == 2
 
 
 # ---------------------------------------------------------------------------

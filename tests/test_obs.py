@@ -7,8 +7,13 @@ The load-bearing properties:
     ``observe_many`` ≡ a loop of ``observe``, ``merge`` preserves every
     moment, and concurrent writers (4 threads on one striped lock) lose
     nothing.
-  - The trace ring is bounded: wraparound drops the oldest events and
-    counts them; the export always validates as Chrome trace-event JSON.
+  - Spans ride the JAX profiler: a capture of device-lane passes holds
+    ``inc.drain > inc.pass > inc.execute > inc.regfile.read_dev >
+    inc.xfer.d2h`` nested on the drain worker's host line, one pass
+    number per batch; with obs off no span is built at all.
+  - The served path's counters are exact where they happen: bytes of
+    every host<->device copy by site, every call's queue wait, and the
+    register file's host time less the copies inside it.
   - Identity: the SAME workload run with obs off and obs fully on
     produces byte-identical replies and final INC-map state — the
     instrumentation observes the data plane, it never steers it.
@@ -20,10 +25,18 @@ The load-bearing properties:
     ``"__switch__"`` section, and ``prometheus_text()`` emits cumulative
     bucket series.
 """
+import contextlib
+import importlib.util
 import json
+import sys
 import threading
+from pathlib import Path
 
+import numpy as np
 import pytest
+
+import jax
+import jax.numpy as jnp
 
 import repro.api as inc
 from repro import obs
@@ -32,13 +45,12 @@ from repro.obs import schema as obs_schema
 from repro.obs import trace as trace_mod
 from repro.obs.metrics import (COUNT_BUCKETS, Counter, Histogram,
                                MetricsRegistry, metric_key)
-from repro.obs.trace import TraceRecorder, validate_chrome_trace
 
 
 @pytest.fixture(autouse=True)
 def _obs_clean():
     """Every test starts and ends with obs off and empty — the module
-    globals (registry, tracer, hook bools) are process-wide."""
+    globals (registry, hook bools) are process-wide."""
     obs.disable()
     obs.reset()
     yield
@@ -206,66 +218,48 @@ def test_prometheus_text_cumulative_buckets():
     assert 'reqs{app="a"} 2' in text
 
 
-# -- trace ring --------------------------------------------------------------
+# -- spans ---------------------------------------------------------------------
 
-def test_trace_ring_wraparound_counts_drops():
-    rec = TraceRecorder(capacity=8)
-    for i in range(12):
-        rec.add_complete(f"e{i}", "t", float(i), 1.0, tid=1)
-    assert len(rec) == 8
-    assert rec.dropped == 4
-    doc = rec.chrome_trace()
-    validate_chrome_trace(doc)
-    names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
-    assert names == [f"e{i}" for i in range(4, 12)]   # oldest evicted
-    assert doc["otherData"]["dropped_events"] == 4
-    rec.clear()
-    assert len(rec) == 0 and rec.dropped == 0
+class _SpanRecorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: keeps every span
+    the data plane builds, as (name, args)."""
+
+    def __init__(self):
+        self.built = []
+
+    def __call__(self, name, **args):
+        self.built.append((name, args))
+        return contextlib.nullcontext()
 
 
-def test_trace_sampling_stride_is_deterministic():
-    obs.enable(trace=True, trace_stride=3)
-    sampled = 0
-    for _ in range(9):
-        ctx = trace_mod.maybe_start("batch", "APP", n=1)
-        if ctx is not None:
-            sampled += 1
-            trace_mod.phase("inner", trace_mod.now_us())
-            trace_mod.end(ctx)
-    # whatever phase the global counter is in, 9 consecutive batches at
-    # stride 3 sample exactly 3
-    assert sampled == 3
-    doc = obs.chrome_trace()
-    validate_chrome_trace(doc)
-    by_name = {}
-    for ev in doc["traceEvents"]:
-        by_name[ev["name"]] = by_name.get(ev["name"], 0) + 1
-    assert by_name["batch"] == 3 and by_name["inner"] == 3
+@pytest.fixture
+def spans(monkeypatch):
+    rec = _SpanRecorder()
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", rec)
+    return rec
 
 
-def test_spans_are_noops_when_off():
-    with obs.trace_span("user"):                 # off: NULL_SPAN
+def test_spans_are_noops_when_off(spans):
+    with obs.trace_span("user"):                 # off: a null context
         pass
-    with trace_mod.span("phase"):                # no active ctx either
-        pass
-    assert len(obs.tracer()) == 0
-    obs.enable(trace=True)
+    assert not obs.enabled() and spans.built == []
+    obs.enable(metrics=False)                    # spans alone
+    assert obs.enabled() and not obs.metrics_enabled()
     with obs.trace_span("user", step=1):
         pass
-    assert any(e["name"] == "user"
-               for e in obs.chrome_trace()["traceEvents"])
+    assert spans.built == [("user", {"step": 1})]
+    obs.enable()
+    assert obs.metrics_enabled()
 
 
-def test_validate_chrome_trace_rejects_garbage():
-    with pytest.raises(ValueError):
-        validate_chrome_trace({"events": []})
-    with pytest.raises(ValueError):
-        validate_chrome_trace({"traceEvents": [{"ph": "Z", "pid": 1,
-                                                "tid": 1}]})
-    with pytest.raises(ValueError):
-        validate_chrome_trace({"traceEvents": [
-            {"ph": "X", "pid": 1, "tid": 1, "name": "a", "ts": 0,
-             "dur": -1}]})
+def test_pass_takes_its_batch_number(spans):
+    with trace_mod.batch("inc.drain", n=2) as b:
+        trace_mod.pass_span("inc.pass")
+        trace_mod.pass_span("inc.pass")          # a handler's nested pass
+    trace_mod.pass_span("inc.pass")              # an inline call's pass
+    drain, first, nested, alone = [a["seq"] for _, a in spans.built]
+    assert drain == b.seq and first == drain
+    assert len({first, nested, alone}) == 3
 
 
 # -- strict trigger accounting (satellite b) ---------------------------------
@@ -313,7 +307,7 @@ def test_identity_obs_off_vs_on():
     """The whole point of the guard structure: enabling metrics+tracing
     must not change a single byte of what the data plane computes."""
     base = json.dumps(_workload(), sort_keys=True)
-    obs.enable(trace=True, trace_stride=1)
+    obs.enable()
     traced = json.dumps(_workload(), sort_keys=True)
     obs.disable()
     again = json.dumps(_workload(), sort_keys=True)
@@ -338,8 +332,8 @@ def test_disabled_runtime_snapshot_has_no_quantiles():
     assert snap["metrics"]["counters"] == {}    # nothing recorded
 
 
-def test_workers4_snapshot_validates_and_reports_quantiles():
-    obs.enable(trace=True, trace_stride=2)
+def test_workers4_snapshot_validates_and_reports_quantiles(spans):
+    obs.enable()
     with inc.IncRuntime(workers=4) as rt:
         rt.server.register("Push", lambda req: {"payload": "ack"})
         stub = rt.make_stub(ObsProbe, n_slots=256)
@@ -366,7 +360,11 @@ def test_workers4_snapshot_validates_and_reports_quantiles():
     assert snap["switch"]["segments"]
     hists = snap["metrics"]["histograms"]
     assert any(k.startswith("inc_pipeline_pass_us") for k in hists)
-    validate_chrome_trace(obs.chrome_trace())
+    # every drained batch's pass carries the batch's number
+    drains = [a["seq"] for n, a in spans.built if n == "inc.drain"]
+    passes = {a["seq"] for n, a in spans.built if n == "inc.pass"}
+    assert drains and len(set(drains)) == len(drains)
+    assert set(drains) <= passes
 
 
 def test_per_runtime_histograms_are_isolated():
@@ -388,3 +386,186 @@ def test_per_runtime_histograms_are_isolated():
     b = one_runtime()
     # same workload, fresh histograms: counts reflect ONE runtime's calls
     assert a["drained_calls"] == b["drained_calls"] == 8
+
+
+# -- the served path's spans and counters -------------------------------------
+
+@inc.service(app="OBS-DEV")
+class DevGrad:
+    """The device lane with the ``copy`` clear, as SyncAgtr serves it."""
+    @inc.rpc(request_msg="NewGrad", reply_msg="AgtrGrad")
+    def Update(self, tensor: inc.Agg[inc.FPArray](
+            precision=6, clear="copy", device=True)
+            ) -> {"tensor": inc.Get[inc.FPArray]}: ...
+
+
+def _chunks(n_elems: int, k: int) -> list:
+    """Fresh device chunks with no element that quantizes to zero, so a
+    ``copy`` clear writes back every register."""
+    base = jnp.linspace(0.5, 1.5, n_elems, dtype=jnp.float32)
+    return [base * (i + 1) for i in range(k)]
+
+
+def _device_calls(rt, n_elems: int, k: int) -> None:
+    stub = rt.make_stub(DevGrad, n_slots=n_elems)
+    for f in [stub.Update(tensor=c) for c in _chunks(n_elems, k)]:
+        f.result()["tensor"].block_until_ready()
+
+
+def _sums(snap: dict, name: str) -> dict:
+    """{labels: sum} of a histogram (or value of a counter) by label set."""
+    out = {}
+    for section in ("counters", "histograms"):
+        for k, v in snap[section].items():
+            if k.startswith(name + "{"):
+                out[k[len(name):]] = v["sum"] if isinstance(v, dict) else v
+    return out
+
+
+def test_profiler_capture_nests_device_lane_on_the_drain_worker(tmp_path):
+    n = 128
+    obs.enable()
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, n, 2)               # compiles outside the capture
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _device_calls(rt, n, 6)
+        finally:
+            jax.profiler.stop_trace()
+    lines = trace_mod.captured_spans(tmp_path)
+    holding = [name for name, evs in lines.items()
+               if any(e[0] == "inc.drain" for e in evs)]
+    assert holding == ["inc-drain-0"]
+    evs = lines["inc-drain-0"]
+    pulls = [e for e in evs if e[0] == "inc.xfer.d2h"
+             and e[3]["site"] == "regfile.read_raw"]
+    assert len(pulls) == 6
+    for pull in pulls:
+        chain = sorted((e for e in evs if e[1] <= pull[1] and pull[2] <= e[2]),
+                       key=lambda e: (e[1], -e[2]))
+        assert [e[0] for e in chain] == [
+            "inc.drain", "inc.pass", "inc.execute", "inc.regfile.read_dev",
+            "inc.xfer.d2h"]
+        assert chain[0][3]["seq"] == chain[1][3]["seq"]
+        assert chain[-1][3]["bytes"] == 4 * n
+    drains = sorted(e[3]["seq"] for e in evs if e[0] == "inc.drain")
+    passes = sorted(e[3]["seq"] for e in evs if e[0] == "inc.pass")
+    assert drains == passes and len(set(drains)) == len(drains)
+
+
+def test_obs_off_builds_no_span_and_moves_no_counter(spans):
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, 64, 3)
+    assert spans.built == []
+    snap = obs.registry().snapshot()
+    assert snap["counters"] == {} and snap["histograms"] == {}
+    obs.enable()
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, 64, 3)
+    assert {"inc.drain", "inc.pass", "inc.plane_lock", "inc.stream_modify",
+            "inc.resolve_addrs", "inc.cntfwd_gate", "inc.execute",
+            "inc.regfile.addto_f32", "inc.regfile.addto",
+            "inc.regfile.read_dev", "inc.xfer.d2h", "inc.xfer.h2d",
+            "inc.resolve"} <= {name for name, _ in spans.built}
+
+
+def test_copy_clear_call_moves_twenty_bytes_an_element():
+    n = 256
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, n, 1)               # compiles with obs off
+        obs.enable()
+        _device_calls(rt, n, 1)
+    snap = obs.registry().snapshot()
+    assert _sums(snap, "inc_xfer_bytes_total") == {
+        '{dir="d2h",site="rpc.stream_items"}': 4 * n,     # the request
+        '{dir="h2d",site="regfile.addto_f32"}': 4 * n,    # fused addTo
+        '{dir="d2h",site="regfile.read_raw"}': 4 * n,     # clear's copy
+        '{dir="h2d",site="regfile.addto_int"}': 8 * n,    # clear write-back
+    }
+    assert snap["counters"]['inc_gpv_elems_total{app="OBS-DEV"}'] == n
+    # one timed copy a site, the write-back's two arrays apart
+    counts = {k: h["count"] for k, h in snap["histograms"].items()
+              if k.startswith("inc_xfer_us")}
+    assert sorted(counts.values()) == [1, 1, 1, 2]
+
+
+def test_queue_wait_sums_pick_minus_submit():
+    now = [100.0]
+    policy = inc.DrainPolicy(max_batch=64, max_delay=1e9,
+                             eager_window=False)
+    obs.enable()
+    with inc.IncRuntime(policy=policy, clock=lambda: now[0]) as rt:
+        stub = rt.make_stub(DevGrad, n_slots=32)
+        submits = [100.0 + 0.25 * i for i in range(8)]
+        futs = []
+        for t, c in zip(submits, _chunks(32, 8)):
+            now[0] = t
+            futs.append(stub.Update(tensor=c))
+        now[0] = 110.0                         # the batch is picked here
+        assert rt.drain() == 8
+        for f in futs:
+            f.result()
+    h = obs.registry().snapshot()["histograms"][
+        'inc_queue_wait_us{app="OBS-DEV"}']
+    assert h["count"] == 8
+    assert h["sum"] == pytest.approx(sum((110.0 - t) * 1e6
+                                         for t in submits))
+
+
+def test_regfile_time_less_its_copies_fits_in_the_pass():
+    obs.enable()
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, 128, 6)
+    snap = obs.registry().snapshot()
+    verbs = sum(_sums(snap, "inc_regfile_us").values())
+    copies = sum(v for k, v in _sums(snap, "inc_xfer_us").items()
+                 if 'site="regfile.' in k)
+    passes = sum(_sums(snap, "inc_pipeline_pass_us").values())
+    assert set(_sums(snap, "inc_regfile_us")) == {
+        '{op="addto_f32"}', '{op="addto"}', '{op="read_dev"}'}
+    assert copies > 0
+    assert 0.0 <= verbs - copies <= passes
+
+
+# -- the benchmark's readers of these counters ---------------------------------
+
+_BENCH = Path(__file__).resolve().parents[1] / "bench"
+_READERS = ("sched.queue_wait_us_per_call", "pipeline.xfer_bytes_per_elem",
+            "pipeline.device_wait_us_per_call", "regfile.host_us_per_call")
+
+
+def _reader(name: str):
+    if str(_BENCH) not in sys.path:
+        sys.path.insert(0, str(_BENCH))
+    spec = importlib.util.spec_from_file_location(
+        "reader_" + name.replace(".", "_"), _BENCH / "metrics" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def traced_rehearsal():
+    sys.path.insert(0, str(_BENCH / "tests"))
+    try:
+        from benchutil import rehearse
+    finally:
+        sys.path.remove(str(_BENCH / "tests"))
+    proc, result = rehearse("agtr_1k", trace=1)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return result
+
+
+@pytest.mark.parametrize("name", _READERS)
+def test_served_path_readers_read_a_traced_rehearsal(name, traced_rehearsal):
+    value = traced_rehearsal["metrics"][name]["value"]
+    assert value > 0
+    if name == "pipeline.xfer_bytes_per_elem":
+        # 20 B for a pass's first call; a later call's update rides the
+        # int write-back with the earlier clear (8 B, not 4)
+        assert 20.0 <= value <= 24.0
+    rec = {"obs": None, "trace": None, "window": {}, "peaks": None,
+           "chips": 1}
+    assert _reader(name).read(rec) is None
