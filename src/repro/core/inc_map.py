@@ -434,39 +434,43 @@ class SwitchMemory:
                     seg.regs = ops.device_addto_scatter(seg.regs, o, fv,
                                                         scale)
 
-    def read_f32(self, phys: np.ndarray, scale, need_raw: bool = False
-                 ) -> tuple[jnp.ndarray, np.ndarray | None]:
-        """Fused gather + dequantize read -> (fp32 jax values, raw int
-        registers as numpy when ``need_raw``). The single-segment
-        contiguous case (a dense GPV tensor reply) is one fused kernel;
-        the general case gathers via ``get`` and dequantizes with the
-        same reciprocal formula, so both flavors are bit-identical."""
+    def read_f32(self, phys: np.ndarray, scale, clear: bool = False
+                 ) -> tuple[jnp.ndarray, None]:
+        """Fused gather + dequantize read -> (fp32 jax values, None).
+        With ``clear`` the run reads zero afterwards, a saturated register
+        keeping its sentinel (the reply path's clear). The single-segment
+        contiguous case (a dense GPV tensor reply) is one fused program,
+        which clears the run too; the general case gathers via ``get``,
+        dequantizes with the same reciprocal formula, so both flavors are
+        bit-identical, and clears with ``clear_read``."""
         n = len(phys)
         if n == 0:
-            empty_raw = np.zeros(0, np.int32) if need_raw else None
-            return jnp.zeros(0, jnp.float32), empty_raw
+            return jnp.zeros(0, jnp.float32), None
         seg_ix, off = self._locate(np.asarray(phys))
         if int(seg_ix[0]) == int(seg_ix[-1]):
             seg = self.segments[int(seg_ix[0])]
             o = np.asarray(off, np.int64)
             if seg.device and (n == 1 or bool((np.diff(o) == 1).all())):
                 with seg.lock:
-                    vals, _ = ops.device_read_dense(
-                        seg.regs, int(o[0]), n, scale)
-                    raw = None
-                    if need_raw:
-                        # the clear's copy of the registers, pulled to
-                        # the host behind the kernels queued on them
-                        regs = seg.regs[int(o[0]):int(o[0]) + n]
-                        raw = (_obs.d2h(regs, "regfile.read_raw")
-                               if _obs.ON else np.asarray(regs))
-                return vals, raw
+                    if clear:
+                        vals, seg.regs = ops.device_read_clear_dense(
+                            seg.regs, int(o[0]), n, scale)
+                    else:
+                        vals, _ = ops.device_read_dense(
+                            seg.regs, int(o[0]), n, scale)
+                if clear and _obs.METRICS:
+                    _obs.regfile_clear("device", n)
+                return vals, None
         raw = self.get(phys)
+        if clear:
+            self.clear_read(phys, raw)
+            if _obs.METRICS:
+                _obs.regfile_clear("host", n)
         inv = np.float32(1.0) / np.float32(scale)
         host = raw.astype(np.float32) * inv
         vals = (_obs.h2d(host, "regfile.read_f32") if _obs.ON
                 else jnp.asarray(host))
-        return vals, (raw if need_raw else None)
+        return vals, None
 
     def get(self, phys: np.ndarray) -> np.ndarray:
         # reads take the segment lock too: the host-path kernel updates
@@ -486,6 +490,19 @@ class SwitchMemory:
                         else np.asarray(seg.regs))
                 out[m] = regs[off[m]]
         return out
+
+    def clear_read(self, phys: np.ndarray, raw: np.ndarray) -> None:
+        """The reply path's clear of registers just read as ``raw``: each
+        takes the negation of what was read, which the sticky saturating
+        add turns into zero while a sentinel stays. An update that landed
+        after the read survives it. One entry per address, however often
+        the read named it."""
+        phys, first = np.unique(np.asarray(phys, np.int64),
+                                return_index=True)
+        raw = np.asarray(raw, np.int32)[first]
+        nz = raw != 0
+        if nz.any():
+            self.addto(phys[nz], -raw[nz])
 
     def clear(self, phys: np.ndarray) -> None:
         if not len(phys):
@@ -798,20 +815,19 @@ class ServerAgent:
 
     @_locked(op="read_dev")
     def read_batch_dev(self, logical: np.ndarray, scale,
-                       need_raw: bool = False
-                       ) -> tuple[jnp.ndarray, np.ndarray | None]:
+                       clear: bool = False) -> jnp.ndarray:
         """The device-resident receive lane: batched Map.get returning
-        dequantized fp32 values as a jax array (plus the raw int64
-        registers when the caller must write back a clear). The all-hit /
+        dequantized fp32 values as a jax array; with ``clear`` the keys
+        read zero afterwards (``clear_batch``'s rule). The all-hit /
         no-spill case — the steady dense-tensor regime — is one fused
-        gather+dequantize kernel; any spill or miss falls back to the
-        exact int64 assembly of ``read_batch`` with the same reciprocal
-        dequant formula, so both flavors agree bit-for-bit."""
+        gather+dequantize program, which also clears; any spill or miss
+        falls back to the exact int64 assembly of ``read_batch`` with the
+        same reciprocal dequant formula, so both flavors agree
+        bit-for-bit, and then to ``clear_batch``."""
         logical = np.asarray(logical, np.uint32)
         n = len(logical)
         if n == 0:
-            raw = np.zeros(0, np.int64) if need_raw else None
-            return jnp.zeros(0, jnp.float32), raw
+            return jnp.zeros(0, jnp.float32)
         q = logical.astype(np.int64)
         spill_hit = False
         if self.spill:
@@ -821,16 +837,41 @@ class ServerAgent:
         if not spill_hit and self.mapping:
             hit, slotv = self._map_lookup(q)
             if bool(hit.all()):
-                vals, raw32 = self.switch.read_f32(
-                    self.base + slotv, scale, need_raw=need_raw)
-                raw = raw32.astype(np.int64) if need_raw else None
-                return vals, raw
+                vals, _ = self.switch.read_f32(self.base + slotv, scale,
+                                               clear)
+                return vals
         raw = self.read_batch(logical)
+        if clear:
+            self.clear_batch(logical, raw)
         inv = np.float32(1.0) / np.float32(scale)
         host = raw.astype(np.float32) * inv
-        vals = (_obs.h2d(host, "regfile.read_dev") if _obs.ON
+        return (_obs.h2d(host, "regfile.read_dev") if _obs.ON
                 else jnp.asarray(host))
-        return vals, (raw if need_raw else None)
+
+    @_locked(op="clear")
+    def clear_batch(self, logical: np.ndarray, raw: np.ndarray) -> None:
+        """The reply path's clear of a Get's keys, where the read did not
+        clear them itself: each key takes off the value ``raw`` it was
+        just read at, from its register if mapped and from the spill if
+        not. So it reads zero, but a saturated register keeps its sentinel
+        and an update that landed after the read survives. A clear is not
+        addTo traffic: it moves no hit, miss or byte count and no LRU
+        window, and grants no mapping."""
+        logical = np.asarray(logical, np.uint32)
+        n = len(logical)
+        q, first = np.unique(logical.astype(np.int64), return_index=True)
+        raw = np.asarray(raw, np.int64)[first]
+        nz = raw != 0
+        q, raw = q[nz], raw[nz]
+        hit = np.zeros(len(q), bool)
+        if len(q) and self.mapping:
+            hit, slotv = self._map_lookup(q)
+            if hit.any():
+                self.switch.clear_read(self.base + slotv[hit], raw[hit])
+        for l, v in zip(q[~hit].tolist(), raw[~hit].tolist()):
+            self.spill[l] -= v
+        if _obs.METRICS:
+            _obs.regfile_clear("host", n)
 
     @_locked
     def spill_host(self, pairs: list[tuple[int, int]]) -> None:

@@ -454,9 +454,8 @@ def _open_pass(channel: Channel, calls: list[_PlannedCall],
     """Flush an enclosing pass's buffer and account the pass."""
     if channel.active_buf is not None:
         # nested pass (a handler's inline follow-up call on its own
-        # channel): the enclosing pass's buffered updates — including
-        # deferred reply-path clears — happened-before this call, and
-        # re-reading pre-clear state here would double-apply the clear
+        # channel): the enclosing pass's buffered updates happened-before
+        # this call
         channel.active_buf.flush()
     channel.touch()
     channel.stats.calls += len(calls)
@@ -632,15 +631,20 @@ def _phase_execute(channel: Channel, host_server: Server,
                 use_dev = (seg is not None and c.array_reply
                            and c.device_plan
                            and getattr(server, "device", False))
+                # clear policy (copy: the read is the server's copy;
+                # shadow/lazy live on the device path,
+                # core/clear_policy.py): the keys read zero right after
+                # the read, a saturated register keeping its sentinel
+                clear = c.nf.clear in POLICIES
                 if use_dev:
-                    # device GPV reply: one fused gather+dequantize kernel,
-                    # the reply is a device-resident fp32 jax array — the
-                    # int32 registers never materialize host-side (raw is
-                    # pulled back only when a clear must write them back)
+                    # device GPV reply: one fused gather+dequantize
+                    # program, which also clears; the reply is a
+                    # device-resident fp32 jax array and the int32
+                    # registers never materialize host-side
                     logs = c.agent.dense_addrs(len(seg))
-                    vals_dev, raw = server.read_batch_dev(
-                        logs, scale, need_raw=(c.nf.clear in POLICIES))
-                    c.reply[fname] = vals_dev.reshape(seg.shape)
+                    c.reply[fname] = server.read_batch_dev(
+                        logs, scale, clear).reshape(seg.shape)
+                    clear = False
                 elif seg is not None:
                     # GPV reply: one address-table slice, one gather, one
                     # vectorized dequantize — for the addTo stream's echo
@@ -669,23 +673,8 @@ def _phase_execute(channel: Channel, host_server: Server,
                            else np.zeros(0, np.int64))
                     c.reply[fname] = {k: int(r) / scale
                                       for k, r in zip(keys, raw)}
-                if c.nf.clear in POLICIES:
-                    # copy: values are already backed up server-side (the
-                    # read above); shadow/lazy semantics are exercised on
-                    # the device path (core/clear_policy.py) — here clear
-                    # empties the map. The clear rides the ordered update
-                    # buffer instead of issuing its own kernel pass: the
-                    # next Map.get (or the final flush) applies it together
-                    # with any interleaved addTo — one reply-path pass per
-                    # flush, not one per cleared call. No earlier observer
-                    # exists: handlers must not read INC state, CntFwd
-                    # counters live on disjoint keys, every later get
-                    # flushes first, and a nested pass (handler inline
-                    # call) flushes this buffer on entry via
-                    # channel.active_buf.
-                    nz = raw != 0
-                    if nz.any():
-                        buf.addto(logs[nz], -raw[nz])
+                if clear:
+                    server.clear_batch(logs, raw)
             c.completed = True
     finally:
         channel.active_buf = prev_buf

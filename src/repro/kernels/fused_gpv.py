@@ -15,6 +15,9 @@ verbs through ONE kernel launch each:
   - ``fused_read_pallas``: receive side — dequantize (reciprocal
     multiply) a contiguous slot range plus the overflow-sentinel mask,
     fused; the reply value block never exists as int32 in host memory.
+  - ``fused_read_clear_pallas``: the same read, and in the same program
+    the reply path's copy clear of the run (sentinels kept), so the
+    registers never travel to the host to be cleared.
   - ``fused_fold_pallas``: quantize N rounds and fold them with the
     saturating add (client-side local aggregation).
 
@@ -199,3 +202,24 @@ def fused_read_pallas(regs: jax.Array, start: jax.Array, n: int,
         interpret=resolve_interpret(interpret),
     )(_scalar(inv), _tiles(cur, rows))
     return _flat(vals, n), _flat(mask, n) != 0
+
+
+def fused_read_clear_pallas(regs: jax.Array, start: jax.Array, n: int,
+                            live: jax.Array, scale: jax.Array, *,
+                            interpret: bool | None = None
+                            ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``fused_read_pallas`` over slots [start, start+n), and the segment
+    with the first ``live`` slots of that run cleared: each register to
+    zero, but a sentinel kept. That is what adding each register's
+    negation gives under the sticky saturating add, without the add.
+    ``live`` is a traced count, so a read bucketed past its run clears
+    only the run and one program serves every run length of a bucket.
+    Returns (values, overflow mask, new regs)."""
+    vals, mask = fused_read_pallas(regs, start, n, scale,
+                                   interpret=interpret)
+    start = jnp.asarray(start, jnp.int32)
+    cur = jax.lax.dynamic_slice(regs, (start,), (n,))
+    keep = mask | (jnp.arange(n, dtype=jnp.int32) >= live)
+    new = jax.lax.dynamic_update_slice(
+        regs, jnp.where(keep, cur, jnp.zeros_like(cur)), (start,))
+    return vals, mask, new

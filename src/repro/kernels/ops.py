@@ -37,6 +37,7 @@ from repro.kernels.dequantize import dequantize_pallas
 from repro.kernels.flash_attn import (flash_attention_chunked_ref,
                                       flash_attention_pallas)
 from repro.kernels.fused_gpv import (fused_addto_pallas, fused_fold_pallas,
+                                     fused_read_clear_pallas,
                                      fused_read_pallas, fused_scatter_pallas)
 from repro.kernels.inc_agg import sat_add_pallas
 from repro.kernels.pack_int8 import pack_int8_pallas, unpack_int8_pallas
@@ -373,8 +374,10 @@ def _device_scatter_int_jit(regs, idx, vals):
     return sparse_addto_pallas(regs, idx, vals)
 
 
-@partial(jax.jit, static_argnames=("n",))
-def _fused_read_jit(regs, start, n, scale):
+@partial(jax.jit, static_argnames=("n", "clear"))
+def _fused_read_jit(regs, start, n, scale, clear=False, live=None):
+    if clear:
+        return fused_read_clear_pallas(regs, start, n, live, scale)
     return fused_read_pallas(regs, start, n, scale)
 
 
@@ -418,9 +421,9 @@ def device_addto_scatter(regs, idx, fvals, scale) -> jax.Array:
 def device_addto_int(regs, idx, vals) -> jax.Array:
     """Saturating scatter-add of an already-quantized int32 stream into a
     device register file — the int lane of a DeviceSegment (spill
-    restores, clear write-backs, host-quantized fallbacks). Runs the
-    Pallas kernel on every backend so the segment stays a jnp array.
-    Power-of-two padded with (idx=0, val=0) no-ops."""
+    restores, host-quantized updates, a reply's clear outside a fused
+    read). Runs the Pallas kernel on every backend so the segment stays a
+    jnp array. Power-of-two padded with (idx=0, val=0) no-ops."""
     k = int(idx.shape[0])
     if k == 0:
         return regs
@@ -438,20 +441,37 @@ def device_addto_int(regs, idx, vals) -> jax.Array:
                    vals)
 
 
+def _read_bucket(regs, start: int, n: int) -> int:
+    """A read's power-of-two length, or ``n`` where the bucket would run
+    past the segment end (one extra jit entry)."""
+    bucket = 1 << (n - 1).bit_length()
+    return n if start + bucket > int(regs.shape[0]) else bucket
+
+
 def device_read_dense(regs, start: int, n: int, scale
                       ) -> tuple[jax.Array, jax.Array]:
     """Fused gather + dequantize of slots [start, start+n) -> (fp32
     values, bool overflow-sentinel mask), both jnp. Reads are bucketed to
-    a power-of-two length and sliced back; a bucket that would run past
-    the segment end reads at exact length (one extra jit entry)."""
+    a power-of-two length and sliced back."""
     if n == 0:
         return (jnp.zeros(0, jnp.float32), jnp.zeros(0, jnp.bool_))
-    bucket = 1 << (n - 1).bit_length()
-    if start + bucket > int(regs.shape[0]):
-        bucket = n
     vals, mask = _launch("fused_read", n, _fused_read_jit, regs, start,
-                         bucket, scale)
+                         _read_bucket(regs, start, n), scale)
     return vals[:n], mask[:n]
+
+
+def device_read_clear_dense(regs, start: int, n: int, scale
+                            ) -> tuple[jax.Array, jax.Array]:
+    """``device_read_dense``'s values, and the segment with the run
+    cleared in the same program (kernels/fused_gpv.py
+    ``fused_read_clear_pallas``: each register of [start, start+n) to
+    zero, a sentinel kept) -> (fp32 values, new regs). The bucket past
+    the run is read, never cleared."""
+    if n == 0:
+        return jnp.zeros(0, jnp.float32), regs
+    vals, _, regs = _launch("fused_read", n, _fused_read_jit, regs, start,
+                            _read_bucket(regs, start, n), scale, True, n)
+    return vals[:n], regs
 
 
 @partial(jax.jit, static_argnames=("block_rows",))

@@ -601,15 +601,19 @@ class RemoteSwitchMemory(SwitchMemory):
         return self._wire(remote,
                           lambda: super(RemoteSwitchMemory, self).get(phys))
 
-    def read_f32(self, phys: np.ndarray, scale, need_raw: bool = False):
+    def read_f32(self, phys: np.ndarray, scale, clear: bool = False):
         if self.fallback_active:
-            return super().read_f32(phys, scale, need_raw)
+            return super().read_f32(phys, scale, clear)
         raw = self.get(phys)
         if self.fallback_active:             # degraded mid-read
-            return super().read_f32(phys, scale, need_raw)
+            return super().read_f32(phys, scale, clear)
         inv = np.float32(1.0) / np.float32(scale)
         vals = jnp.asarray(raw.astype(np.float32) * inv)
-        return vals, (raw if need_raw else None)
+        if clear:
+            self.clear_read(phys, raw)
+            if _obs.METRICS:
+                _obs.regfile_clear("host", len(phys))
+        return vals, None
 
     def clear(self, phys: np.ndarray) -> None:
         if not len(phys):

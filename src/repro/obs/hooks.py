@@ -12,7 +12,7 @@ instrumentation with one of them::
     if _obs.METRICS:
         _obs.pipeline_pass(app, n, source, t0)
 
-    out = _obs.d2h(x, "regfile.read_raw") if _obs.ON else np.asarray(x)
+    out = _obs.d2h(x, "regfile.get") if _obs.ON else np.asarray(x)
 
 so the *disabled* build — the default — pays exactly one module-global
 load + bool branch per site: there is no hook object to call, no registry
@@ -130,6 +130,13 @@ def regfile_op(op: str, t0: float) -> None:
     _metrics.REGISTRY.histogram("inc_regfile_us", buckets=_US,
                                 op=op).observe(
         (time.perf_counter() - t0) * 1e6)
+
+
+def regfile_clear(lane: str, n: int) -> None:
+    """Registers a reply's clear emptied: ``lane="device"`` inside the
+    program that read them, ``"host"`` by a clear after the read."""
+    _metrics.REGISTRY.counter("inc_regfile_clear_elems_total",
+                              lane=lane).inc(n)
 
 
 # -- host<->device copies ----------------------------------------------------

@@ -28,12 +28,15 @@ import jax
 import jax.numpy as jnp
 
 import repro.api as inc
+from repro import obs
 from repro.core import rpc as rpc_mod
+from repro.core.channel import Controller
 from repro.core.inc_map import ServerAgent, SwitchMemory, quantize_stream
 from repro.core.rpc import NetRPC
 from repro.core.runtime import DrainPolicy, IncRuntime
 from repro.kernels.backend import (accelerator_present, pallas_mode,
                                    resolve_interpret)
+from repro.kernels.constants import INT32_MAX, INT32_MIN, SAT_MAX
 from repro.kernels.fused_gpv import (fused_addto_pallas, fused_read_pallas,
                                      fused_scatter_pallas)
 
@@ -158,28 +161,69 @@ def test_addto_f32_miss_and_spill_stats_parity():
                                   dev.read_batch(probe))
 
 
-def test_read_batch_dev_fallback_equals_fast_path():
+def test_read_batch_dev_fallback_equals_fast_path(monkeypatch):
     """read_batch_dev's single-segment contiguous fast path and its
     general fallback (spill present / partial hits) obey the same
-    reciprocal-dequant contract."""
-    dev = _agent(True, n_slots=16)
-    fv = np.arange(16, dtype=np.float32) / 3
-    dev.addto_batch_f32(np.arange(16, dtype=np.uint32), fv, 10 ** 4)
-    logs = np.arange(16, dtype=np.uint32)
-    vals, raw = dev.read_batch_dev(logs, 10 ** 4, need_raw=True)
-    assert isinstance(vals, jnp.ndarray) and raw is not None
-    want_raw = dev.read_batch(logs)
-    np.testing.assert_array_equal(raw, want_raw)
+    reciprocal-dequant contract, and clear alike: the fused program and
+    the clear verb after the fallback's read leave the same registers."""
+    verb_clears = []
+    clear_batch = ServerAgent.clear_batch
+    monkeypatch.setattr(ServerAgent, "clear_batch",
+                        lambda self, l, r: verb_clears.append(len(l))
+                        or clear_batch(self, l, r))
     inv = np.float32(1.0) / np.float32(10.0 ** 4)
+    logs = np.arange(16, dtype=np.uint32)
+    fast, slow = _agent(True, n_slots=16), _agent(True, n_slots=16)
+    for a in (fast, slow):
+        a.addto_batch_f32(logs, np.arange(16, dtype=np.float32) / 3,
+                          10 ** 4)
+    want_raw = fast.read_batch(logs)
+    vals = fast.read_batch_dev(logs, 10 ** 4, True)
+    assert isinstance(vals, jnp.ndarray) and verb_clears == []
     np.testing.assert_array_equal(np.asarray(vals),
                                   want_raw.astype(np.float32) * inv)
-    # force the fallback: a spilled key makes the probe non-contiguous
-    dev.spill_host([(999, 7)])
-    vals2, _ = dev.read_batch_dev(np.array([999, 3], np.uint32), 10 ** 4)
+    # force the fallback: a spilled key in the probe
+    slow.spill_host([(999, 7)])
+    probe = np.append(logs, np.uint32(999))
+    vals2 = slow.read_batch_dev(probe, 10 ** 4, True)
+    assert verb_clears == [17]
+    np.testing.assert_array_equal(np.asarray(vals2)[:16], np.asarray(vals))
+    assert np.asarray(vals2)[16] == np.float32(7) * inv
+    phys = fast.base + np.arange(16)
+    np.testing.assert_array_equal(fast.switch.get(phys),
+                                  slow.switch.get(phys))
+    assert not fast.read_batch(probe).any()
+    assert not slow.read_batch(probe).any()
+    # without a clear the fallback reads and leaves the state alone
+    slow.spill_host([(999, 5)])
+    slow.addto_batch_f32(logs[3:4], np.ones(1, np.float32), 10 ** 4)
+    vals3 = slow.read_batch_dev(np.array([999, 3], np.uint32), 10 ** 4)
     np.testing.assert_array_equal(
-        np.asarray(vals2),
-        dev.read_batch(np.array([999, 3], np.uint32)).astype(np.float32)
+        np.asarray(vals3),
+        slow.read_batch(np.array([999, 3], np.uint32)).astype(np.float32)
         * inv)
+    assert slow.read_batch(np.array([999, 3], np.uint32)).tolist() == [
+        5, 10 ** 4]
+
+
+@pytest.mark.parametrize("device", [False, True])
+def test_clear_batch_takes_off_what_was_read(device):
+    """The clear after a read takes off the value that was read, so an
+    update landing between the two survives it, in a register as in the
+    spill; a sentinel stays, and the clear moves no addTo count."""
+    a = _agent(device, n_slots=4)
+    logs = np.array([0, 1, 2, 3, 9], np.uint32)   # key 9 finds no slot
+    a.addto_batch(logs, np.array([5, INT32_MAX, -7, 0, 11], np.int64))
+    assert 9 not in a.mapping and a.spill[9] == 11
+    raw = a.read_batch(logs)
+    assert raw.tolist() == [5, INT32_MAX, -7, 0, 11]
+    a.addto_batch(logs[[0, 4]], np.array([3, 4], np.int64))
+    counts = (a.hits, a.misses, a.inc_bytes, a.host_bytes,
+              a.seen_this_window)
+    a.clear_batch(np.append(logs, logs[:1]), np.append(raw, raw[:1]))
+    assert a.read_batch(logs).tolist() == [3, INT32_MAX, 0, 0, 4]
+    assert (a.hits, a.misses, a.inc_bytes, a.host_bytes,
+            a.seen_this_window) == counts
 
 
 def test_device_kernel_duplicate_addresses_match_host():
@@ -197,6 +241,171 @@ def test_device_kernel_duplicate_addresses_match_host():
     for j, v in zip(idx, q):
         want[j] += v
     np.testing.assert_array_equal(got, want.astype(np.int32))
+
+
+# ---- the copy clear: device lane == host lane == numpy oracle ----------------
+
+def _copy_lanes(app, n, *, lead=0, tail=0, seg_slots=64):
+    """{False: host stub, True: device stub}, each with ``clear="copy"``
+    on its own 2-segment register file, its partition behind ``lead``
+    slots of another and followed by ``tail`` slots of a third."""
+    stubs = {}
+    for device in (False, True):
+        @inc.service(app=f"{app}-{'dev' if device else 'host'}")
+        class Svc:
+            @inc.rpc(request_msg="NewGrad", reply_msg="AgtrGrad")
+            def Update(self, tensor: inc.Agg[inc.FPArray](
+                    precision=6, clear="copy", device=device)
+                    ) -> {"tensor": inc.Get[inc.FPArray]}: ...
+        switch = SwitchMemory(2, seg_slots)
+        if lead:
+            assert switch.reserve(1000, lead)
+        stubs[device] = NetRPC(Controller(switch)).make_stub(Svc,
+                                                             n_slots=n)
+        if tail:
+            assert switch.reserve(1001, tail)
+    return stubs
+
+
+def _oracle_pass(regs, chunks):
+    """The numpy oracle of one pass: per call, the saturating sticky add
+    of the chunk's fixed-point values (scale 10**6), the reply read, then
+    the clear (zero, a sentinel kept). Returns the replies' raw ints."""
+    out = []
+    for g in chunks:
+        q = np.rint(np.asarray(g, np.float32) * 10 ** 6).astype(np.int64)
+        for i, v in enumerate(q.tolist()):
+            if abs(regs[i]) != INT32_MAX:
+                regs[i] = (INT32_MAX if regs[i] + v > SAT_MAX else
+                           INT32_MIN if regs[i] + v < -SAT_MAX else
+                           regs[i] + v)
+        out.append(regs.copy())
+        regs[np.abs(regs) != INT32_MAX] = 0
+    return out
+
+
+def _check_copy_pass(stubs, chunks, regs):
+    """One pass of ``chunks`` down both lanes, against the oracle."""
+    inv = np.float32(1.0) / np.float32(10.0 ** 6)
+    want = _oracle_pass(regs, chunks)
+    req = [{"tensor": g} for g in chunks]
+    got_host = [f.result()["tensor"] for f in stubs[False].Update.batch(req)]
+    got_dev = [f.result()["tensor"] for f in stubs[True].Update.batch(req)]
+    for w, h, d in zip(want, got_host, got_dev):
+        np.testing.assert_array_equal(np.asarray(h), w / 10.0 ** 6)
+        np.testing.assert_array_equal(np.asarray(d),
+                                      w.astype(np.float32) * inv)
+    n = len(regs)
+    assert _raw_state(stubs[False], n) == _raw_state(stubs[True], n) \
+        == regs.tolist()
+
+
+def _clear_lanes():
+    snap = obs.registry().snapshot()["counters"]
+    return {k.split('"')[1]: v for k, v in snap.items()
+            if k.startswith("inc_regfile_clear_elems_total{")}
+
+
+COPY_CASES = {
+    # name: (lead, n, tail, calls a pass, the device lane's clear)
+    "pass1": (0, 48, 0, 1, "device"),
+    "pass4": (0, 48, 0, 4, "device"),
+    "pass8": (0, 48, 0, 8, "device"),
+    # [16, 64): the bucket of 64 runs past the segment, so exact length
+    "run_ends_at_segment_end": (16, 48, 0, 4, "device"),
+    # [0, 40) read in a bucket of 64 over the next partition's slots,
+    # which the clear must leave alone
+    "bucket_past_run": (0, 40, 24, 4, "device"),
+    # [16, 80) over both segments: the read gathers, then clears
+    "run_over_two_segments": (16, 64, 0, 4, "host"),
+}
+
+
+@pytest.mark.parametrize("case", list(COPY_CASES))
+def test_copy_clear_matches_host_and_oracle(case):
+    lead, n, tail, k, lane = COPY_CASES[case]
+    stubs = _copy_lanes(f"DP-copy-{case}", n, lead=lead, tail=tail)
+    neighbor = np.arange(lead + n, lead + n + tail)
+    for dev in (False, True):
+        stubs[dev].agents["Update"].server.switch.addto(
+            neighbor, np.arange(1, tail + 1, dtype=np.int32) * 7)
+    rng = np.random.RandomState(31)
+    regs = np.zeros(n, np.int64)
+    obs.enable()
+    try:
+        for _ in range(3):
+            _check_copy_pass(stubs, [(rng.randn(n) * 3).astype(np.float32)
+                                     for _ in range(k)], regs)
+        lanes = _clear_lanes()
+    finally:
+        obs.disable()
+        obs.reset()
+    # every call cleared its n registers on each lane, the device lane
+    # inside the read's program where the run allows
+    assert lanes == ({"host": 2 * 3 * k * n} if lane == "host" else
+                     {"host": 3 * k * n, "device": 3 * k * n})
+    for dev in (False, True):
+        srv = stubs[dev].agents["Update"].server
+        assert srv.switch.get(neighbor).tolist() == \
+            (np.arange(1, tail + 1) * 7).tolist()
+        # a clear is not addTo traffic
+        assert srv.hits + srv.misses == 3 * k * n
+        assert srv.inc_bytes == 8 * srv.hits
+    assert _stats(stubs[False]) == _stats(stubs[True])
+
+
+def test_copy_clear_keeps_a_sentinel_on_both_lanes():
+    stubs = _copy_lanes("DP-copy-sentinel", 32)
+    regs = np.zeros(32, np.int64)
+    g = np.linspace(-2, 2, 32).astype(np.float32)
+    _check_copy_pass(stubs, [g], regs)       # maps every key
+    # two registers near the ends of the range, which the next update
+    # carries past them
+    near = np.array([2_000_000_000, -2_000_000_000], np.int64)
+    for stub in stubs.values():
+        stub.agents["Update"].server.addto_batch(
+            np.array([3, 17], np.uint32), near)
+    regs[[3, 17]] = near
+    hot = g.copy()
+    hot[[3, 17]] = [500.0, -500.0]
+    _check_copy_pass(stubs, [hot, g], regs)
+    for stub in stubs.values():
+        raw = stub.agents["Update"].server.read_batch(
+            np.array([3, 17, 4], np.uint32))
+        assert raw.tolist() == [INT32_MAX, INT32_MIN, 0]
+    _check_copy_pass(stubs, [g, g], regs)    # the sentinels stay sticky
+    assert _stats(stubs[False]) == _stats(stubs[True])
+
+
+def test_copy_clear_in_a_nested_handler_pass():
+    """A handler's inline call on its own channel flushes the enclosing
+    call's update, reads both and clears them; the enclosing call then
+    reads the cleared registers — alike on both lanes."""
+    stubs = _copy_lanes("DP-copy-nested", 16)
+    g = np.linspace(0.5, 2.0, 16).astype(np.float32)
+    inner = {}
+    for dev, stub in stubs.items():
+        seen = []
+
+        def handler(req, stub=stub, seen=seen):
+            if not seen:
+                seen.append(None)            # the inner call's own handler
+                seen[0] = np.asarray(
+                    stub.Update(tensor=2 * g).result()["tensor"])
+            return {}
+        stub.legacy.runtime.server.register("Update", handler)
+        outer = np.asarray(stub.Update(tensor=g).result()["tensor"])
+        assert not outer.any()
+        inner[dev] = seen[0]
+    want = (np.rint(g * 10 ** 6).astype(np.int64)
+            + np.rint(2 * g * 10 ** 6).astype(np.int64))
+    np.testing.assert_array_equal(inner[False], want / 10.0 ** 6)
+    np.testing.assert_array_equal(
+        inner[True], want.astype(np.float32)
+        * (np.float32(1.0) / np.float32(10.0 ** 6)))
+    assert _raw_state(stubs[False], 16) == _raw_state(stubs[True], 16) \
+        == [0] * 16
+    assert _stats(stubs[False]) == _stats(stubs[True])
 
 
 # ---- sharded runtime: device channel under concurrent drains -----------------

@@ -8,8 +8,8 @@ The load-bearing properties:
     moment, and concurrent writers (4 threads on one striped lock) lose
     nothing.
   - Spans ride the JAX profiler: a capture of device-lane passes holds
-    ``inc.drain > inc.pass > inc.execute > inc.regfile.read_dev >
-    inc.xfer.d2h`` nested on the drain worker's host line, one pass
+    ``inc.drain > inc.pass > inc.execute > inc.regfile.addto_f32 >
+    inc.xfer.h2d`` nested on the drain worker's host line, one pass
     number per batch; with obs off no span is built at all.
   - The served path's counters are exact where they happen: bytes of
     every host<->device copy by site, every call's queue wait, and the
@@ -439,17 +439,31 @@ def test_profiler_capture_nests_device_lane_on_the_drain_worker(tmp_path):
                if any(e[0] == "inc.drain" for e in evs)]
     assert holding == ["inc-drain-0"]
     evs = lines["inc-drain-0"]
-    pulls = [e for e in evs if e[0] == "inc.xfer.d2h"
-             and e[3]["site"] == "regfile.read_raw"]
-    assert len(pulls) == 6
-    for pull in pulls:
-        chain = sorted((e for e in evs if e[1] <= pull[1] and pull[2] <= e[2]),
-                       key=lambda e: (e[1], -e[2]))
-        assert [e[0] for e in chain] == [
-            "inc.drain", "inc.pass", "inc.execute", "inc.regfile.read_dev",
-            "inc.xfer.d2h"]
-        assert chain[0][3]["seq"] == chain[1][3]["seq"]
-        assert chain[-1][3]["bytes"] == 4 * n
+
+    def chain(inner):
+        return sorted((e for e in evs
+                       if e[1] <= inner[1] and inner[2] <= e[2]),
+                      key=lambda e: (e[1], -e[2]))
+
+    # every call's update is one fused addTo, its values one h2d copy
+    pushes = [e for e in evs if e[0] == "inc.xfer.h2d"
+              and e[3]["site"] == "regfile.addto_f32"]
+    assert len(pushes) == 6
+    for push in pushes:
+        c = chain(push)
+        assert [e[0] for e in c] == [
+            "inc.drain", "inc.pass", "inc.execute", "inc.regfile.addto_f32",
+            "inc.xfer.h2d"]
+        assert c[0][3]["seq"] == c[1][3]["seq"]
+        assert c[-1][3]["bytes"] == 4 * n
+    # every call's read clears on the device: nothing crosses inside it
+    reads = [e for e in evs if e[0] == "inc.regfile.read_dev"]
+    assert len(reads) == 6
+    for read in reads:
+        assert [e[0] for e in chain(read)] == [
+            "inc.drain", "inc.pass", "inc.execute", "inc.regfile.read_dev"]
+        assert not [e for e in evs if e[0].startswith("inc.xfer.")
+                    and read[1] <= e[1] and e[2] <= read[2]]
     drains = sorted(e[3]["seq"] for e in evs if e[0] == "inc.drain")
     passes = sorted(e[3]["seq"] for e in evs if e[0] == "inc.pass")
     assert drains == passes and len(set(drains)) == len(drains)
@@ -464,14 +478,19 @@ def test_obs_off_builds_no_span_and_moves_no_counter(spans):
     obs.enable()
     with inc.IncRuntime() as rt:
         _device_calls(rt, 64, 3)
+    built = {name for name, _ in spans.built}
     assert {"inc.drain", "inc.pass", "inc.plane_lock", "inc.stream_modify",
             "inc.resolve_addrs", "inc.cntfwd_gate", "inc.execute",
-            "inc.regfile.addto_f32", "inc.regfile.addto",
-            "inc.regfile.read_dev", "inc.xfer.d2h", "inc.xfer.h2d",
-            "inc.resolve"} <= {name for name, _ in spans.built}
+            "inc.regfile.addto_f32", "inc.regfile.read_dev",
+            "inc.xfer.d2h", "inc.xfer.h2d", "inc.resolve"} <= built
+    # the clear is inside the read: no int write-back, no clear verb
+    assert not {"inc.regfile.addto", "inc.regfile.clear"} & built
 
 
 def test_copy_clear_call_moves_twenty_bytes_an_element():
+    """A copy-clear call moves its request and its fused addTo's values,
+    8 B an element. The 12 B more of a clear that pulled the registers
+    and wrote their negation back through the int scatter are gone."""
     n = 256
     with inc.IncRuntime() as rt:
         _device_calls(rt, n, 1)               # compiles with obs off
@@ -481,14 +500,12 @@ def test_copy_clear_call_moves_twenty_bytes_an_element():
     assert _sums(snap, "inc_xfer_bytes_total") == {
         '{dir="d2h",site="rpc.stream_items"}': 4 * n,     # the request
         '{dir="h2d",site="regfile.addto_f32"}': 4 * n,    # fused addTo
-        '{dir="d2h",site="regfile.read_raw"}': 4 * n,     # clear's copy
-        '{dir="h2d",site="regfile.addto_int"}': 8 * n,    # clear write-back
     }
     assert snap["counters"]['inc_gpv_elems_total{app="OBS-DEV"}'] == n
-    # one timed copy a site, the write-back's two arrays apart
+    # one timed copy a site
     counts = {k: h["count"] for k, h in snap["histograms"].items()
               if k.startswith("inc_xfer_us")}
-    assert sorted(counts.values()) == [1, 1, 1, 2]
+    assert sorted(counts.values()) == [1, 1]
 
 
 def test_queue_wait_sums_pick_minus_submit():
@@ -524,7 +541,7 @@ def test_regfile_time_less_its_copies_fits_in_the_pass():
                  if 'site="regfile.' in k)
     passes = sum(_sums(snap, "inc_pipeline_pass_us").values())
     assert set(_sums(snap, "inc_regfile_us")) == {
-        '{op="addto_f32"}', '{op="addto"}', '{op="read_dev"}'}
+        '{op="addto_f32"}', '{op="read_dev"}'}
     assert copies > 0
     assert 0.0 <= verbs - copies <= passes
 
@@ -533,7 +550,8 @@ def test_regfile_time_less_its_copies_fits_in_the_pass():
 
 _BENCH = Path(__file__).resolve().parents[1] / "bench"
 _READERS = ("sched.queue_wait_us_per_call", "pipeline.xfer_bytes_per_elem",
-            "pipeline.device_wait_us_per_call", "regfile.host_us_per_call")
+            "pipeline.device_wait_us_per_call", "regfile.host_us_per_call",
+            "regfile.device_clear_share")
 
 
 def _reader(name: str):
@@ -563,9 +581,43 @@ def test_served_path_readers_read_a_traced_rehearsal(name, traced_rehearsal):
     value = traced_rehearsal["metrics"][name]["value"]
     assert value > 0
     if name == "pipeline.xfer_bytes_per_elem":
-        # 20 B for a pass's first call; a later call's update rides the
-        # int write-back with the earlier clear (8 B, not 4)
-        assert 20.0 <= value <= 24.0
+        # every call's request pull and fused addTo, 4 B each (the CPU
+        # backend keeps no host copy of a request chunk)
+        assert value == pytest.approx(8.0)
+    if name == "regfile.device_clear_share":
+        assert value == 100.0
     rec = {"obs": None, "trace": None, "window": {}, "peaks": None,
            "chips": 1}
     assert _reader(name).read(rec) is None
+
+
+@inc.service(app="OBS-HOST")
+class HostGrad:
+    """``DevGrad`` on host registers: its clear is the clear verb's."""
+    @inc.rpc(request_msg="NewGrad", reply_msg="AgtrGrad")
+    def Update(self, tensor: inc.Agg[inc.FPArray](
+            precision=6, clear="copy")
+            ) -> {"tensor": inc.Get[inc.FPArray]}: ...
+
+
+def test_clear_counts_its_lane_and_the_share_reads_them():
+    n = 64
+    obs.enable()
+    with inc.IncRuntime() as rt:
+        _device_calls(rt, n, 3)
+    dev_snap = obs.registry().snapshot()
+    assert _sums(dev_snap, "inc_regfile_clear_elems_total") == {
+        '{lane="device"}': 3 * n}
+    with inc.IncRuntime() as rt:
+        stub = rt.make_stub(HostGrad, n_slots=n)
+        for c in _chunks(n, 2):
+            stub.Update(tensor=np.asarray(c)).result()
+    both = obs.registry().snapshot()
+    assert _sums(both, "inc_regfile_clear_elems_total") == {
+        '{lane="device"}': 3 * n, '{lane="host"}': 2 * n}
+    share = _reader("regfile.device_clear_share").read
+    assert share({"obs": dev_snap}) == 100.0
+    assert share({"obs": both}) == pytest.approx(60.0)
+    obs.reset()
+    assert share({"obs": obs.registry().snapshot()}) is None
+    assert share({"obs": None}) is None

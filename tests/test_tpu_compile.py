@@ -6,9 +6,10 @@ compiler would refuse (unaligned dynamic slices, scalar stores to VMEM,
 primitives with no Mosaic lowering) — the faults interpret-mode tests
 cannot see. Every kernel of the device-resident register file is compiled
 at the real size: a 40,000-slot segment (the SwitchMemory default, paper
-§6.1), dense runs of 32,768 slots and the whole segment, 4,096-entry
-scatter streams and the 65,536-entry int bucket a full-segment run pads
-to, plus the (rows, 128)-tiled stream kernels of the ring path.
+§6.1), dense runs of 32,768 slots and the whole segment, reads of 1,024
+and 32,768 slots that also clear them, 4,096-entry scatter streams and
+the 65,536-entry int bucket a full-segment run pads to, plus the
+(rows, 128)-tiled stream kernels of the ring path.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -25,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels.constants import DEFAULT_BLOCK_ROWS, LANES
 from repro.kernels.dequantize import dequantize_pallas
 from repro.kernels.fused_gpv import (fused_addto_pallas, fused_fold_pallas,
+                                     fused_read_clear_pallas,
                                      fused_read_pallas, fused_scatter_pallas)
 from repro.kernels.inc_agg import sat_add_pallas
 from repro.kernels.quantize import quantize_pallas
@@ -43,6 +45,11 @@ def _read(n):
     return lambda r, s, c: fused_read_pallas(r, s, n, c, interpret=False)
 
 
+def _read_clear(n):
+    return lambda r, s, live, c: fused_read_clear_pallas(
+        r, s, n, live, c, interpret=False)
+
+
 CASES = [
     ("fused_addto_1024", _addto,
      [((SLOTS,), I32), ((), I32), ((1024,), F32), ((), F32)]),
@@ -55,6 +62,10 @@ CASES = [
      [((SLOTS,), I32), ((), I32), ((), F32)]),
     ("fused_read_full_segment", _read(SLOTS),
      [((SLOTS,), I32), ((), I32), ((), F32)]),
+    ("fused_read_clear_1024", _read_clear(1024),
+     [((SLOTS,), I32), ((), I32), ((), I32), ((), F32)]),
+    ("fused_read_clear_dense32768", _read_clear(32768),
+     [((SLOTS,), I32), ((), I32), ((), I32), ((), F32)]),
     ("fused_scatter_4096",
      lambda r, i, f, c: fused_scatter_pallas(r, i, f, c, interpret=False),
      [((SLOTS,), I32), ((4096,), I32), ((4096,), F32), ((), F32)]),

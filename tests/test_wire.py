@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.inc_map import SwitchMemory
+from repro.core.inc_map import ServerAgent, SwitchMemory
+from repro.kernels.constants import INT32_MAX
 from repro.net import (FaultProxy, FaultSpec, RemoteSwitchMemory,
                        SwitchServer, WireTransport)
 from repro.net import protocol as proto
@@ -98,7 +99,7 @@ def test_crash_restart_replay():
 def test_oracle_equivalence_mixed_ops():
     """Mixed addto/addto_f32/clear stream under reorder+dup faults must
     match an in-process SwitchMemory oracle bit-for-bit (including the
-    f32 quantization scale math)."""
+    f32 quantization scale math), and so must a read that clears."""
     spec = FaultSpec(seed=5, loss=0.1, dup=0.15, reorder=0.15)
     srv, px, t, mem = _stack(spec)
     oracle = SwitchMemory(**GEO)
@@ -124,6 +125,16 @@ def test_oracle_equivalence_mixed_ops():
         wire_f, _ = mem.read_f32(phys, scale)
         orac_f, _ = oracle.read_f32(phys, scale)
         assert np.array_equal(np.asarray(wire_f), np.asarray(orac_f))
+        # the reply path's clear keeps a sentinel, over the wire too
+        for m in (mem, oracle):
+            m.addto(phys[5:6], np.array([INT32_MAX], np.int32))
+            m.clear_read(phys[30:40], m.get(phys[30:40]))
+        assert np.array_equal(mem.get(phys), oracle.get(phys))
+        wire_c, _ = mem.read_f32(phys, scale, True)
+        orac_c, _ = oracle.read_f32(phys, scale, True)
+        assert np.array_equal(np.asarray(wire_c), np.asarray(orac_c))
+        assert mem.get(phys).tolist() == oracle.get(phys).tolist() == \
+            [INT32_MAX if i == 5 else 0 for i in range(48)]
         assert t.ctrl("stats")["duplicate_effects"] == {}
     finally:
         _teardown(srv, px, t)
@@ -147,6 +158,60 @@ def test_reserve_mirrors_daemon_placement():
         m1.addto(phys, np.full(20, 7, np.int32))
         t1.barrier()                         # m2's read fences only flow 2
         assert np.array_equal(m2.get(phys), np.full(20, 7, np.int32))
+    finally:
+        t1.close()
+        t2.close()
+        srv.stop()
+
+
+@pytest.mark.parametrize("path", ["read_f32", "agent"])
+def test_clear_keeps_another_writers_update_after_the_read(path):
+    """A reply's clear takes off what the read returned, not what the
+    registers hold when it lands: an addTo that a second client process
+    sends to the same registers between the read and the clear survives
+    it, and a sentinel stays. Both clears are covered: the one inside
+    ``read_f32`` and the agent's ``clear_batch`` after ``read_batch``
+    (the host lane's reply path)."""
+    srv = SwitchServer(track_effects=True, **GEO).start()
+    t1 = WireTransport(srv.address, flow_id=1, w_max=8)
+    t2 = WireTransport(srv.address, flow_id=2, w_max=8)
+    m1 = RemoteSwitchMemory(t1, **GEO)
+    m2 = RemoteSwitchMemory(t2, **GEO)
+    try:
+        logs = np.arange(16, dtype=np.uint32)
+        first = np.arange(1, 17, dtype=np.int32) * 5
+        first[3] = INT32_MAX
+        agent = ServerAgent(m1, gaid=10, n_slots=16)
+        assert agent.granted and m2.reserve(10, 16)
+        agent.addto_batch(logs, first)        # maps every key
+        assert agent.read_batch(logs).tolist() == first.tolist()
+        start = m1.partitions[10][0]
+        phys = start + np.arange(16, dtype=np.int64)
+        read, writes = m1.get, [3]
+
+        def read_then_other_writes(p):
+            out = read(p)
+            while writes:                     # once, right after the read
+                m2.addto(phys, np.full(16, writes.pop(), np.int32))
+                t2.barrier()
+            return out
+
+        m1.get = read_then_other_writes
+        if path == "read_f32":
+            slots = np.array([agent.mapping[int(l)] for l in logs])
+            got, _ = m1.read_f32(agent.base + slots, 100, True)
+            got = np.asarray(got)
+        else:
+            raw = agent.read_batch(logs)
+            agent.clear_batch(logs, raw)
+            got = raw
+        del m1.get
+        np.testing.assert_array_equal(
+            got, first if path == "agent" else
+            first.astype(np.float32) * np.float32(0.01))
+        assert agent.read_batch(logs).tolist() == \
+            [INT32_MAX if i == 3 else 3 for i in range(16)]
+        assert (agent.hits, agent.misses) == (0, 16)   # the first addTo
     finally:
         t1.close()
         t2.close()
