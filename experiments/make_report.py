@@ -12,7 +12,7 @@ DRY = ROOT / "experiments" / "dryrun"
 PERF = ROOT / "experiments" / "perf"
 
 ARCH_ORDER = [
-    "moonshot-v1-16b-a3b", "grok-1-314b", "gemma3-27b", "phi4-mini-3.8b",
+    "moonlight-16b-a3b", "grok-1-314b", "gemma3-27b", "phi4-mini-3.8b",
     "stablelm-1.6b", "qwen2.5-3b", "llama-3.2-vision-90b",
     "recurrentgemma-9b", "mamba2-780m", "whisper-medium"]
 SHAPE_ORDER = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]

@@ -4,7 +4,7 @@ from repro.configs import (  # noqa: F401
     grok_1_314b,
     llama_3_2_vision_90b,
     mamba2_780m,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     phi4_mini_3_8b,
     qwen2_5_3b,
     recurrentgemma_9b,
@@ -13,7 +13,7 @@ from repro.configs import (  # noqa: F401
 )
 
 ALL_ARCHS = (
-    "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "grok-1-314b",
     "gemma3-27b",
     "phi4-mini-3.8b",

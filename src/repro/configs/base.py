@@ -25,6 +25,9 @@ BLOCK_TYPES = (
     "moe",        # causal full attention + MoE FFN
     "ssd",        # Mamba-2 SSD mixer (attention-free, no separate FFN)
     "rglru",      # RG-LRU temporal block + FFN (Griffin/RecurrentGemma)
+    "mla",        # multi-head latent attention + dense SwiGLU (dense_d_ff)
+    "mla_moe",    # multi-head latent attention + sigmoid-routed MoE with
+                  # shared experts (DeepSeek-V3)
 )
 
 FAMILIES = ("dense", "moe", "vlm", "hybrid", "ssm", "audio")
@@ -48,10 +51,26 @@ class ArchConfig:
     tie_embeddings: bool = True
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-6
-    # MoE
+    # MoE: n_experts is the router's width; d_ff the expert width
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25      # softmax "moe" blocks only
+    aux_coef: float = 0.01             # weight of the balance loss
+    # sigmoid-routed MoE (mla_moe blocks, DeepSeek-V3 noaux_tc, one group)
+    n_shared_experts: int = 0          # shared experts of width d_ff each
+    routed_scaling: float = 1.0        # scale of the renormalised gates
+    bias_update_rate: float = 0.0      # selection-bias step per train step
+    # the expert share: this chip holds experts
+    # [expert_rank * held, (expert_rank + 1) * held) of n_experts, where
+    # held = n_experts // expert_parallel, and computes their part alone
+    expert_parallel: int = 1
+    expert_rank: int = 0
+    # MLA (multi-head latent attention; no q compression)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    dense_d_ff: int = 0                # width of "mla" (dense) layers
     # SSM (mamba2)
     ssm_state: int = 0
     ssm_heads: int = 0                 # mamba2 value heads
@@ -73,10 +92,20 @@ class ArchConfig:
         for pat, _ in self.pattern_groups:
             for b in pat:
                 assert b in BLOCK_TYPES, b
+        assert self.n_experts % self.expert_parallel == 0
+        assert 0 <= self.expert_rank < self.expert_parallel
 
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts // self.expert_parallel
+
+    @property
+    def expert_offset(self) -> int:
+        return self.expert_rank * self.experts_held
 
     @property
     def vocab_padded(self) -> int:
@@ -103,7 +132,7 @@ class ArchConfig:
         attention; gemma3 is grandfathered in (only 10/62 layers are
         global, with seq-sharded KV)."""
         kinds = {b for p, _ in self.pattern_groups for b in p}
-        full_ctx = kinds & {"global", "moe", "selfcross"}
+        full_ctx = kinds & {"global", "moe", "selfcross", "mla", "mla_moe"}
         return not full_ctx or self.name.startswith("gemma3")
 
     def n_params(self) -> int:
@@ -130,6 +159,11 @@ class ArchConfig:
             d_model=64, n_heads=heads, n_kv_heads=kv,
             head_dim=32,
             d_ff=128 if self.d_ff else 0,
+            dense_d_ff=256 if self.dense_d_ff else 0,
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
             vocab=512,
             pattern_groups=tuple(groups),
             window=32,

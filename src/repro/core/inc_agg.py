@@ -297,6 +297,20 @@ def reduce_scatter_dim(g: jax.Array, dim: int, dp_axes: tuple[str, ...],
     return jnp.moveaxis(out, 0, dim)
 
 
+def sum_counts(c: jax.Array, dp_axes: tuple[str, ...]) -> jax.Array:
+    """Exact sum over the data-parallel ranks of an integer count array,
+    through the saturating int32 ring: each hop is the switch's Map.addTo
+    on integers as they are, with no fixed-point step (counts far below
+    the saturation sentinels add exactly). Returns the sum on every rank.
+    """
+    flat = c.reshape(-1).astype(jnp.int32)
+    n = _dp_size_static(dp_axes)
+    pad = (-flat.shape[0]) % n
+    out = ring.hierarchical_all_reduce(jnp.pad(flat, (0, pad)), dp_axes,
+                                       _ref.sat_add)
+    return out[:flat.shape[0]].reshape(c.shape)
+
+
 def all_gather_dim(x: jax.Array, dim: int, dp_axes: tuple[str, ...],
                    cfg: IncAggConfig) -> jax.Array:
     """Inverse of reduce_scatter_dim (used to rebuild updated params)."""

@@ -1,8 +1,11 @@
 """Deterministic synthetic data pipeline.
 
-Two generators:
+Three generators:
   - `uniform`: i.i.d. tokens — used by benchmarks and the dry-run (shape
     stand-ins only).
+  - `zipf`: i.i.d. tokens whose frequencies follow Zipf's law (id i drawn
+    with probability proportional to (i + 1) ** -zipf_a), as natural text's
+    do: repeated tokens route alike, so MoE loads come out uneven.
   - `bigram`: tokens sampled from a fixed random first-order Markov chain,
     so a model CAN learn it — train_mini's loss must visibly fall toward
     the chain's conditional entropy (paper Fig. 6 analogue validates the
@@ -15,6 +18,9 @@ re-run step consumes identical data.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import lru_cache
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -26,8 +32,17 @@ class DataConfig:
     batch: int
     seq_len: int
     seed: int = 0
-    kind: str = "bigram"         # "bigram" | "uniform"
+    kind: str = "bigram"         # "bigram" | "uniform" | "zipf"
     temperature: float = 0.7     # bigram sharpness (lower = more learnable)
+    zipf_a: float = 1.0          # zipf exponent
+
+
+@lru_cache(maxsize=8)
+def zipf_cdf(vocab: int, a: float) -> np.ndarray:
+    """Cumulative Zipf probabilities of ids 0..vocab-1 (float64 sums,
+    float32 table), the same table wherever it is built."""
+    w = np.arange(1, vocab + 1, dtype=np.float64) ** -a
+    return (np.cumsum(w) / w.sum()).astype(np.float32)
 
 
 def _transition_logits(cfg: DataConfig) -> jax.Array:
@@ -42,6 +57,11 @@ def make_batch(cfg: DataConfig, step) -> dict:
         toks = jax.random.randint(key, (cfg.batch, cfg.seq_len + 1), 0,
                                   cfg.vocab, jnp.int32)
         return {"tokens": toks}
+    if cfg.kind == "zipf":
+        u = jax.random.uniform(key, (cfg.batch, cfg.seq_len + 1), jnp.float32)
+        ids = jnp.searchsorted(jnp.asarray(zipf_cdf(cfg.vocab, cfg.zipf_a)),
+                               u, side="right")
+        return {"tokens": jnp.minimum(ids, cfg.vocab - 1).astype(jnp.int32)}
     trans = _transition_logits(cfg)
     k0, kseq = jax.random.split(key)
     first = jax.random.randint(k0, (cfg.batch,), 0, cfg.vocab, jnp.int32)

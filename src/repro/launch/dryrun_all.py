@@ -18,7 +18,7 @@ from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 
 ARCHS = (
-    "moonshot-v1-16b-a3b", "grok-1-314b", "gemma3-27b", "phi4-mini-3.8b",
+    "moonlight-16b-a3b", "grok-1-314b", "gemma3-27b", "phi4-mini-3.8b",
     "stablelm-1.6b", "qwen2.5-3b", "llama-3.2-vision-90b",
     "recurrentgemma-9b", "mamba2-780m", "whisper-medium",
 )

@@ -397,6 +397,8 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
     o_manual = rules.manual_specs(ospecs, manual)
 
     hook = (make_param_gather(dims, manual, inc) if mode == "fsdp" else None)
+    routing = _Routing(cfg, params_shapes) if api.has_routing_stats(cfg) \
+        else None
 
     def loss_fn(p, mb):
         if mode == "fsdp":
@@ -414,15 +416,17 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
 
         def micro(carry, mb):
             gacc, lacc = carry
-            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            (loss, met), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, mb)
             gacc = jax.tree.map(
                 lambda a, g: a + g.astype(jnp.float32), gacc, grads)
-            return (gacc, lacc + loss), None
+            return (gacc, lacc + loss), (met["moe"] if routing else None)
 
-        (grads, loss_sum), _ = jax.lax.scan(micro, (g0, 0.0), mb_batch)
+        (grads, loss_sum), moe = jax.lax.scan(micro, (g0, 0.0), mb_batch)
         inv = 1.0 / (n_micro * n_dp)
         loss = jax.lax.psum(loss_sum / n_micro, manual) / n_dp
+        if routing:
+            moe = routing.totals(moe, manual)
 
         # ---- INC aggregation over dp (SyncAgtr) ---------------------------
         flat_g = jax.tree_util.tree_flatten(grads)[0]
@@ -430,8 +434,10 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
             dims, is_leaf=lambda x: isinstance(x, int))[0]
         treedef = jax.tree_util.tree_structure(grads)
         agg = []
-        for g, d in zip(flat_g, flat_d):
-            if mode == "fsdp" and d >= 0:
+        for i, (g, d) in enumerate(zip(flat_g, flat_d)):
+            if routing and i in routing.bias_sites:
+                agg.append(None)             # state: no gradient stream
+            elif mode == "fsdp" and d >= 0:
                 agg.append(g * inv)          # scattered+summed in backward
             elif d >= 0:
                 agg.append(inc_agg.reduce_scatter_dim(g, d, manual, inc)
@@ -442,7 +448,7 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
         sq_scat = sum(jnp.sum(jnp.square(g))
                       for g, d in zip(agg, flat_d) if d >= 0)
         sq_repl = sum(jnp.sum(jnp.square(g))
-                      for g, d in zip(agg, flat_d) if d < 0)
+                      for g, d in zip(agg, flat_d) if d < 0 and g is not None)
         gnorm = jnp.sqrt(jax.lax.psum(sq_scat, manual) + sq_repl)
         factor = adamw.clip_factor(gnorm, opt_cfg.grad_clip)
         lr = adamw.schedule(opt_cfg, step_idx)
@@ -453,8 +459,17 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
         flat_vv = jax.tree_util.tree_flatten(opt["v"])[0]
         flat_p = jax.tree_util.tree_flatten(params)[0]
         new_p, new_m, new_mm, new_vv = [], [], [], []
-        for g, d, ms, m1, v1, pl in zip(agg, flat_d, flat_m, flat_mm,
-                                        flat_vv, flat_p):
+        for i, (g, d, ms, m1, v1, pl) in enumerate(zip(
+                agg, flat_d, flat_m, flat_mm, flat_vv, flat_p)):
+            if g is None:
+                # the selection bias: one step of the load rule, no AdamW,
+                # no weight decay, no clipping
+                b = routing.bias_step(ms, moe["load_by_site"], i)
+                new_p.append(b.astype(pl.dtype))
+                new_m.append(b)
+                new_mm.append(m1)
+                new_vv.append(v1)
+                continue
             st = adamw.adamw_leaf({"master": ms, "m": m1, "v": v1},
                                   g * factor, lr=lr, cfg=opt_cfg,
                                   step=step_idx, wd_on=adamw.decay_mask(ms))
@@ -467,16 +482,21 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
             new_vv.append(st["v"])
         unf = partial(jax.tree_util.tree_unflatten, treedef)
         metrics = {"loss": loss, "gnorm": gnorm, "lr": lr}
+        if routing:
+            metrics.update({k: moe[k] for k in _Routing.OUTPUTS})
         return unf(new_p), {"master": unf(new_m), "m": unf(new_mm),
                             "v": unf(new_vv)}, metrics
 
+    metric_specs = {"loss": P(), "gnorm": P(), "lr": P()}
+    if routing:
+        metric_specs.update(moe_load=P(), moe_rows=P(), moe_held_max=P(),
+                            moe_ids=P(dp_spec))
     step = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_manual, {"master": o_manual, "m": o_manual,
                              "v": o_manual}, bspecs, P()),
         out_specs=(p_manual, {"master": o_manual, "m": o_manual,
-                              "v": o_manual},
-                   {"loss": P(), "gnorm": P(), "lr": P()}),
+                              "v": o_manual}, metric_specs),
         axis_names=set(manual), check_vma=False)
 
     p_shard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
@@ -510,7 +530,90 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
             "n_model": n_model, "manual": manual, "kind": "train",
             "param_shardings": p_shard, "opt_shardings": o_shard,
             "params_shapes": params_shapes, "dims": dims}
+    if routing:
+        meta["moe_layers"] = routing.layers
+        meta["moe_load_elems"] = len(routing.layers) * cfg.n_experts
     return Program(fn=jitted, arg_specs=arg_specs, mesh=mesh, meta=meta)
+
+
+class _Routing:
+    """The train step's share of sigmoid-routed MoE blocks: their routing
+    stats summed over microbatches and data-parallel ranks, and the
+    selection bias moved by DeepSeek-V3's load rule,
+    ``b += rate * sign(mean load - load)``, from the loads of every rank
+    summed exactly through the INC integer ring."""
+
+    OUTPUTS = ("moe_load", "moe_rows", "moe_held_max", "moe_ids")
+
+    def __init__(self, cfg: ArchConfig, params_shapes):
+        self.rate = cfg.bias_update_rate
+        # (group, slot) of each MoE block, in stats order, and the model
+        # layer of each of its repeats
+        self.sites, self.layers, first = [], [], 0
+        for gi, (pat, n) in enumerate(cfg.pattern_groups):
+            for si, bt in enumerate(pat):
+                if bt == "mla_moe":
+                    self.sites.append((gi, f"s{si}"))
+                    self.layers += [first + r * len(pat) + si
+                                    for r in range(n)]
+            first += n * len(pat)
+        flat = jax.tree_util.tree_flatten_with_path(params_shapes)[0]
+        # flat leaf index of each selection bias -> its (group, slot)
+        self.bias_sites = {i: (path[1].idx, path[2].key)
+                           for i, (path, _) in enumerate(flat)
+                           if rules.is_state_leaf(path)}
+
+    def totals(self, moe, manual) -> dict:
+        """Per-microbatch stats (leaves (n_micro, n_rep, ...)) -> the
+        step's outputs, layers concatenated in ``self.layers`` order."""
+        per = [moe[gi][slot] for gi, slot in self.sites]
+        cat = lambda k: jnp.concatenate([p[k] for p in per], 1)  # noqa: E731
+        load = jnp.sum(cat("load"), 0)
+        rows = jnp.sum(cat("rows"), 0)
+        held_max = jnp.sum(cat("held_max"), 0)
+        ids = cat("ids")                     # (n_micro, L, mb, S, k)
+        n_micro, n_layers, mb = ids.shape[:3]
+        ids = jnp.moveaxis(ids, 2, 1).reshape(n_micro * mb, n_layers,
+                                              *ids.shape[3:])
+        load = inc_agg.sum_counts(load, manual)
+        by_site, at = {}, 0
+        for (gi, slot), p in zip(self.sites, per):
+            n = p["load"].shape[1]
+            by_site[(gi, slot)] = load[at:at + n]
+            at += n
+        return {"moe_load": load, "load_by_site": by_site,
+                "moe_rows": jax.lax.psum(rows, manual),
+                "moe_held_max": jax.lax.psum(held_max, manual),
+                "moe_ids": ids}
+
+    def bias_step(self, b, load_by_site: dict, leaf: int) -> jax.Array:
+        load = load_by_site[self.bias_sites[leaf]].astype(jnp.float32)
+        return b + self.rate * jnp.sign(
+            jnp.mean(load, axis=-1, keepdims=True) - load)
+
+
+def read_step(m: dict, meta: dict) -> float:
+    """The step's loss as a float. With the obs registry on, the step's
+    MoE routing outputs come back in the same transfer and are counted:
+    ``inc_moe_routed_rows_total``, ``inc_moe_held_rows_total``,
+    ``inc_moe_computed_rows_total`` (padding included) and
+    ``inc_moe_held_max_rows_total`` (the fullest held expert's rows) per
+    model layer, and the elements of the integer load aggregation,
+    ``inc_agg_int_elems_total{what="moe_load"}``."""
+    if not (_obs.METRICS and "moe_rows" in m):
+        return float(m["loss"])
+    loss, rows, held_max = jax.device_get((m["loss"], m["moe_rows"],
+                                           m["moe_held_max"]))
+    reg = inc.metrics()
+    for layer, r, h in zip(meta["moe_layers"], rows, held_max):
+        lab = str(layer)
+        reg.counter("inc_moe_routed_rows_total", layer=lab).inc(int(r[0]))
+        reg.counter("inc_moe_held_rows_total", layer=lab).inc(int(r[1]))
+        reg.counter("inc_moe_computed_rows_total", layer=lab).inc(int(r[2]))
+        reg.counter("inc_moe_held_max_rows_total", layer=lab).inc(int(h))
+    reg.counter("inc_agg_int_elems_total", what="moe_load").inc(
+        meta["moe_load_elems"])
+    return float(loss)
 
 
 def init_state(program: Program, cfg: ArchConfig, rng=None):

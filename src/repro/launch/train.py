@@ -80,7 +80,7 @@ def train_loop(*, arch: str | ArchConfig, inc_mode: str, steps_n: int,
         b = pipeline.make_batch(dcfg, s)
         b = pipeline.add_modality_stubs(b, cfg, batch)
         params, opt, m = prog.fn(params, opt, b, jnp.int32(s))
-        losses.append(float(m["loss"]))
+        losses.append(steps.read_step(m, prog.meta))
         ran += 1
         telemetry.push({"loss_sum": losses[-1], "steps": 1,
                         "gnorm_sum": float(m["gnorm"])})

@@ -25,8 +25,6 @@ from repro.models.common import (COMPUTE_DTYPE, dense_init, rms_norm,
                                  rms_norm_init, sinusoidal_positions,
                                  stack_layers)
 
-AUX_COEF = 0.01
-
 
 # ---------------------------------------------------------------------------
 # init
@@ -70,20 +68,29 @@ def init_params(rng: jax.Array, cfg: ArchConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def _scan_train(params: dict, cfg: ArchConfig, x, ctx: Ctx, remat: bool):
+    """Returns (x, summed balance term, routing stats): per pattern group
+    a dict by slot of the sigmoid-routed MoE blocks' stats, stacked over
+    the group's repeats (empty for every other block)."""
     aux = jnp.zeros((), jnp.float32)
+    stats = []
     for gi, ((pat, n), gp) in enumerate(zip(cfg.pattern_groups,
                                             params["groups"])):
         def body(carry, pslice, _pat=pat, _gi=gi):
             h, a = carry
             pslice = ctx.gather("groups", _gi, pslice)
+            ys = {}
             for i, bt in enumerate(_pat):
-                h, ai = blocks.block_train(pslice[f"s{i}"], bt, h, ctx)
+                h, ai, st = blocks.block_train_stats(pslice[f"s{i}"], bt, h,
+                                                     ctx)
                 a = a + ai
-            return (h, a), None
+                if st is not None:
+                    ys[f"s{i}"] = st
+            return (h, a), ys
         if remat:
             body = jax.checkpoint(body)
-        (x, aux), _ = jax.lax.scan(body, (x, aux), gp)
-    return x, aux
+        (x, aux), ys = jax.lax.scan(body, (x, aux), gp)
+        stats.append(ys)
+    return x, aux, tuple(stats)
 
 
 def _scan_prefill(params: dict, cfg: ArchConfig, x, ctx: Ctx):
@@ -192,33 +199,51 @@ def logits_of(params: dict, cfg: ArchConfig, x: jax.Array) -> jax.Array:
 # public entry points
 # ---------------------------------------------------------------------------
 
-def forward(params: dict, cfg: ArchConfig, batch: dict, remat: bool = True,
-            param_gather=None) -> tuple[jax.Array, jax.Array]:
-    """batch["tokens"]: (B, S) int32 -> (logits (B,S,V), aux)."""
+def _forward(params: dict, cfg: ArchConfig, batch: dict, remat: bool,
+             param_gather) -> tuple[jax.Array, jax.Array, tuple]:
     tokens = batch["tokens"]
     s = tokens.shape[1]
     positions = jnp.arange(s)
     ctx = Ctx(cfg=cfg, positions=positions, param_gather=param_gather,
               memory=_memory(params, cfg, batch, param_gather))
     x = _embed(params, cfg, tokens, positions)
-    x, aux = _scan_train(params, cfg, x, ctx, remat)
+    x, aux, stats = _scan_train(params, cfg, x, ctx, remat)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    return logits_of(params, cfg, x), aux
+    return logits_of(params, cfg, x), aux, stats
+
+
+def forward(params: dict, cfg: ArchConfig, batch: dict, remat: bool = True,
+            param_gather=None) -> tuple[jax.Array, jax.Array]:
+    """batch["tokens"]: (B, S) int32 -> (logits (B,S,V), aux)."""
+    logits, aux, _ = _forward(params, cfg, batch, remat, param_gather)
+    return logits, aux
+
+
+def has_routing_stats(cfg: ArchConfig) -> bool:
+    """True if the model has sigmoid-routed MoE blocks, whose routing
+    stats ``train_loss`` returns under ``"moe"``."""
+    return any(bt == "mla_moe" for pat, _ in cfg.pattern_groups
+               for bt in pat)
 
 
 def train_loss(params: dict, cfg: ArchConfig, batch: dict,
                remat: bool = True, param_gather=None
                ) -> tuple[jax.Array, dict]:
-    """batch["tokens"]: (B, S+1) -> next-token cross-entropy (+ MoE aux)."""
+    """batch["tokens"]: (B, S+1) -> next-token cross-entropy (+ MoE aux);
+    with sigmoid-routed MoE blocks the metrics carry their routing stats
+    (``_scan_train``) under ``"moe"``."""
     tokens = batch["tokens"]
     inp = {**batch, "tokens": tokens[:, :-1]}
     tgt = tokens[:, 1:]
-    logits, aux = forward(params, cfg, inp, remat, param_gather)
+    logits, aux, stats = _forward(params, cfg, inp, remat, param_gather)
     lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
     tl = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
     ce = jnp.mean(lse - tl.astype(jnp.float32))
-    loss = ce + AUX_COEF * aux
-    return loss, {"ce": ce, "aux": aux}
+    loss = ce + cfg.aux_coef * aux
+    metrics = {"ce": ce, "aux": aux}
+    if has_routing_stats(cfg):
+        metrics["moe"] = stats
+    return loss, metrics
 
 
 def prefill(params: dict, cfg: ArchConfig, batch: dict, param_gather=None

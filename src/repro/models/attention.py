@@ -21,7 +21,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import COMPUTE_DTYPE, apply_rope, dense_init
+from repro.models.common import (COMPUTE_DTYPE, apply_rope, dense_init,
+                                 rms_norm)
 
 NEG_INF = -1e30
 # above this seq len, causal attention scans over query chunks (peak
@@ -116,11 +117,14 @@ def attn_full(q, k, v, n_kv: int, causal: bool) -> jax.Array:
     return _merge(_attend(_group(q, n_kv), k, v, mask))
 
 
-def attn_causal_chunked(q, k, v, n_kv: int, q_chunk: int = Q_CHUNK
-                        ) -> jax.Array:
+def attn_causal_chunked(q, k, v, n_kv: int, q_chunk: int = Q_CHUNK,
+                        remat: bool = False) -> jax.Array:
     """Memory-efficient causal attention: scan over query chunks, each
-    attending to the full (masked) KV. Peak transient is (B,H,Cq,S)."""
-    b, s, h, hd = q.shape
+    attending to the full (masked) KV. Peak transient is (B,H,Cq,S);
+    with ``remat`` each chunk is recomputed in the backward pass, so the
+    backward keeps one chunk's scores at a time, not all of them. The
+    value head size may differ from the query/key head size (MLA)."""
+    b, s, h, _ = q.shape
     assert s % q_chunk == 0, (s, q_chunk)
     nq = s // q_chunk
     qg = _group(q, n_kv)
@@ -131,8 +135,10 @@ def attn_causal_chunked(q, k, v, n_kv: int, q_chunk: int = Q_CHUNK
         mask = (jnp.arange(s)[None, :] <= qpos[:, None])[None, None]
         return None, _attend(qs, k, v, mask)
 
-    _, outs = jax.lax.scan(body, None, jnp.arange(nq))   # (nq,B,Cq,KV,G,hd)
-    o = jnp.moveaxis(outs, 0, 1).reshape(b, s, n_kv, h // n_kv, hd)
+    if remat:
+        body = jax.checkpoint(body)
+    _, outs = jax.lax.scan(body, None, jnp.arange(nq))   # (nq,B,Cq,KV,G,hv)
+    o = jnp.moveaxis(outs, 0, 1).reshape(b, s, n_kv, h // n_kv, v.shape[-1])
     return _merge(o)
 
 
@@ -297,7 +303,7 @@ def _decode_attend(q, k_cache, v_cache, valid, n_kv: int,
         den = jax.lax.psum(den, seq_axes)
         o = jax.lax.psum(o.astype(jnp.float32), seq_axes)
     o = o.astype(jnp.float32) / jnp.maximum(den[..., None], 1e-30)
-    return o.reshape(b, 1, h, hd).astype(v_cache.dtype)
+    return o.reshape(b, 1, h, v_cache.shape[-1]).astype(v_cache.dtype)
 
 
 def decode_cross_attn(p: dict, x1: jax.Array, k_mem, v_mem, n_kv: int):
@@ -318,3 +324,97 @@ def memory_kv(p: dict, memory: jax.Array):
         k = k + p["bk"]
         v = v + p["bv"]
     return k, v
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (MLA, DeepSeek-V2/V3; no query compression)
+# ---------------------------------------------------------------------------
+# Keys and values come from one latent per position: [c_kv, k_pe] = h W_kv_a,
+# c_kv RMS-normed, [k_nope, v] = c_kv W_kv_b per head; the rotary part of
+# the key (k_pe) is one vector shared by every head. Queries are h W_q,
+# split per head into q_nope and q_pe. Scores are
+# [q_nope, q_pe] . [k_nope, k_pe] / sqrt(nope + rope). The rotary halves
+# use this module's split layout (apply_rope); the published checkpoint
+# stores them interleaved, which is the same map up to a fixed permutation
+# of the rope columns of W_q and W_kv_a. The decode cache holds the normed
+# latent and the rotated k_pe, not per-head keys and values.
+
+MLA_Q_CHUNK = 512
+# eps of the latent's RMSNorm: the DeepSeek-V2/V3 modeling code's RMSNorm
+# default, which their configs do not override (rms_norm_eps is the
+# blocks' own norms)
+MLA_LATENT_EPS = 1e-6
+
+
+def init_mla(key, d_model: int, n_heads: int, kv_rank: int, nope: int,
+             rope: int, v_dim: int) -> dict:
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d_model, n_heads, nope + rope),
+                         fan_in=d_model),
+        "wkv_a": dense_init(ks[1], (d_model, kv_rank + rope), fan_in=d_model),
+        "kv_norm": jnp.zeros((kv_rank,), jnp.float32),
+        "wkv_b": dense_init(ks[2], (kv_rank, n_heads, nope + v_dim),
+                            fan_in=kv_rank),
+        "wo": dense_init(ks[3], (n_heads, v_dim, d_model),
+                         fan_in=n_heads * v_dim),
+    }
+
+
+def mla_latent(p: dict, x: jax.Array, positions, *, rope: int,
+               theta: float):
+    """x: (B,S,d) -> (normed latent (B,S,rank), rotated k_pe (B,S,rope)):
+    what the decode cache keeps per position."""
+    kv = jnp.einsum("bsd,dc->bsc", x, p["wkv_a"])
+    c, k_pe = kv[..., :-rope], kv[..., -rope:]
+    c = rms_norm(c, p["kv_norm"], MLA_LATENT_EPS)
+    k_pe = apply_rope(k_pe[:, :, None, :], positions, theta)[:, :, 0]
+    return c, k_pe
+
+
+def mla_expand(p: dict, c: jax.Array, k_pe: jax.Array, *, nope: int):
+    """Latent -> per-head keys (nope + rope) and values."""
+    kvb = jnp.einsum("bsc,chk->bshk", c, p["wkv_b"])
+    k_nope, v = kvb[..., :nope], kvb[..., nope:]
+    h = k_nope.shape[2]
+    k_rot = jnp.broadcast_to(k_pe[:, :, None, :],
+                             k_pe.shape[:2] + (h, k_pe.shape[-1]))
+    return jnp.concatenate([k_nope, k_rot], axis=-1), v
+
+
+def mla_query(p: dict, x: jax.Array, positions, *, nope: int, theta: float):
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    q_pe = apply_rope(q[..., nope:], positions, theta)
+    return jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+
+
+def mla_train(p: dict, x: jax.Array, *, nope: int, rope: int, theta: float,
+              positions: jax.Array) -> jax.Array:
+    """Causal MLA over a full sequence: (B,S,d) -> (B,S,d)."""
+    with jax.named_scope("mla"):
+        q = mla_query(p, x, positions, nope=nope, theta=theta)
+        c, k_pe = mla_latent(p, x, positions, rope=rope, theta=theta)
+        k, v = mla_expand(p, c, k_pe, nope=nope)
+        h = q.shape[2]
+        if x.shape[1] > CHUNKED_THRESHOLD:
+            o = attn_causal_chunked(q, k, v, h,
+                                    min(MLA_Q_CHUNK, x.shape[1]), remat=True)
+        else:
+            o = attn_full(q, k, v, h, causal=True)
+        return out_proj(p, o)
+
+
+def mla_decode(p: dict, x1: jax.Array, c_cache, pe_cache, pos, *, nope: int,
+               rope: int, theta: float):
+    """x1: (B,1,d) against a latent cache of positions < pos; returns
+    (out (B,1,d), c_cache, pe_cache) with position ``pos`` written."""
+    posb = jnp.broadcast_to(pos, (x1.shape[0], 1))
+    q = mla_query(p, x1, posb, nope=nope, theta=theta)
+    c, k_pe = mla_latent(p, x1, posb, rope=rope, theta=theta)
+    slot = pos.astype(jnp.int32)
+    c_cache = jax.lax.dynamic_update_slice_in_dim(c_cache, c, slot, 1)
+    pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, k_pe, slot, 1)
+    k, v = mla_expand(p, c_cache, pe_cache, nope=nope)
+    valid = jnp.arange(c_cache.shape[1]) <= pos
+    out = _decode_attend(q, k, v, valid, q.shape[2], None)
+    return out_proj(p, out), c_cache, pe_cache

@@ -11,6 +11,8 @@ Cache entries per block type:
   selfcross    self {"k","v"} + static {"mk","mv"}
   ssd          {"state"}: (B,H,P,N) fp32, {"conv"}: (B,K-1,Cc)
   rglru        {"state"}: (B,rnn) fp32, {"conv"}: (B,K-1,rnn)
+  mla/mla_moe  {"ckv"}: (B, S, kv_lora_rank) normed latent,
+               {"kpe"}: (B, S, qk_rope_head_dim) rotated shared rope key
 """
 from __future__ import annotations
 
@@ -53,6 +55,18 @@ class Ctx:
 def init_block(key, bt: str, cfg: ArchConfig) -> dict:
     d, eps = cfg.d_model, cfg.norm_eps
     ks = jax.random.split(key, 3)
+    if bt in ("mla", "mla_moe"):
+        p = {"n1": rms_norm_init(d), "n2": rms_norm_init(d),
+             "attn": attn.init_mla(ks[0], d, cfg.n_heads, cfg.kv_lora_rank,
+                                   cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                                   cfg.v_head_dim)}
+        if bt == "mla":
+            p["mlp"] = ffn.init_swiglu(ks[1], d, cfg.dense_d_ff)
+        else:
+            p["moe"] = ffn.init_sigmoid_moe(
+                ks[1], d, cfg.d_ff, cfg.n_experts, cfg.experts_held,
+                cfg.expert_offset, cfg.n_shared_experts)
+        return p
     if bt == "ssd":
         return {"n1": rms_norm_init(d),
                 "mix": init_mamba2(ks[0], d, cfg.ssm_heads, cfg.ssm_head_dim,
@@ -96,11 +110,41 @@ def init_block(key, bt: str, cfg: ArchConfig) -> dict:
 # train (full sequence, no cache)
 # ---------------------------------------------------------------------------
 
+def _mla(p: dict, x: jax.Array, cfg: ArchConfig, positions) -> jax.Array:
+    return attn.mla_train(p["attn"], rms_norm(x, p["n1"], cfg.norm_eps),
+                          nope=cfg.qk_nope_head_dim,
+                          rope=cfg.qk_rope_head_dim, theta=cfg.rope_theta,
+                          positions=positions)
+
+
+def _sigmoid_moe(p: dict, x: jax.Array, cfg: ArchConfig):
+    return ffn.sigmoid_moe(p["moe"], rms_norm(x, p["n2"], cfg.norm_eps),
+                           top_k=cfg.top_k, scaling=cfg.routed_scaling,
+                           held=cfg.experts_held, offset=cfg.expert_offset)
+
+
+def block_train_stats(p: dict, bt: str, x: jax.Array, ctx: Ctx
+                      ) -> tuple[jax.Array, jax.Array, dict | None]:
+    """``block_train`` plus the routing stats of a sigmoid-routed MoE
+    block (``ffn.sigmoid_moe``); None for every other block."""
+    if bt != "mla_moe":
+        return (*block_train(p, bt, x, ctx), None)
+    x = x + _mla(p, x, ctx.cfg, ctx.positions)
+    y, aux, stats = _sigmoid_moe(p, x, ctx.cfg)
+    return x + y, aux, stats
+
+
 def block_train(p: dict, bt: str, x: jax.Array, ctx: Ctx
                 ) -> tuple[jax.Array, jax.Array]:
     cfg = ctx.cfg
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
+    if bt == "mla":
+        x = x + _mla(p, x, cfg, ctx.positions)
+        return x + ffn.swiglu(p["mlp"], rms_norm(x, p["n2"], eps)), aux
+    if bt == "mla_moe":
+        x, aux, _ = block_train_stats(p, bt, x, ctx)
+        return x, aux
     if bt == "ssd":
         y, _, _ = mamba2_train(p["mix"], rms_norm(x, p["n1"], eps),
                                n_heads=cfg.ssm_heads,
@@ -204,6 +248,12 @@ def block_prefill(p: dict, bt: str, x: jax.Array, ctx: Ctx
         mk, mv = attn.memory_kv(p["xattn"], ctx.memory)
         x, _ = block_train(p, bt, x, ctx)
         return x, {"k": k, "v": v, "mk": mk, "mv": mv}
+    if bt in ("mla", "mla_moe"):
+        c, k_pe = attn.mla_latent(p["attn"], rms_norm(x, p["n1"], eps),
+                                  ctx.positions, rope=cfg.qk_rope_head_dim,
+                                  theta=cfg.rope_theta)
+        x, _ = block_train(p, bt, x, ctx)
+        return x, {"ckv": c, "kpe": k_pe}
     # attention blocks: capture roped K/V of the *normed* input
     xn = rms_norm(x, p["n1"], eps)
     k, v = _roped_kv(p["attn"], xn, ctx.positions, cfg.rope_theta)
@@ -254,6 +304,18 @@ def block_decode(p: dict, bt: str, x1: jax.Array, cache: dict,
         x1 = x1 + y
         x1 = x1 + ffn.gelu_mlp(p["mlp"], rms_norm(x1, p["n3"], eps))
         return x1, {"k": k, "v": v, "mk": cache["mk"], "mv": cache["mv"]}
+    if bt in ("mla", "mla_moe"):
+        y, c, pe = attn.mla_decode(p["attn"], rms_norm(x1, p["n1"], eps),
+                                   cache["ckv"], cache["kpe"], pos,
+                                   nope=cfg.qk_nope_head_dim,
+                                   rope=cfg.qk_rope_head_dim,
+                                   theta=cfg.rope_theta)
+        x1 = x1 + y
+        if bt == "mla":
+            x1 = x1 + ffn.swiglu(p["mlp"], rms_norm(x1, p["n2"], eps))
+        else:
+            x1 = x1 + _sigmoid_moe(p, x1, cfg)[0]
+        return x1, {"ckv": c, "kpe": pe}
     # global / local / moe
     window = cfg.window if bt == "local" else None
     seq_axes = None if bt == "local" else ctx.seq_axes
@@ -299,6 +361,10 @@ def cache_entry_shape(bt: str, cfg: ArchConfig, batch: int, seq_len: int
                 "v": sds((batch, seq_len, kv, hd), COMPUTE_DTYPE),
                 "mk": sds((batch, m, kv, hd), COMPUTE_DTYPE),
                 "mv": sds((batch, m, kv, hd), COMPUTE_DTYPE)}
+    if bt in ("mla", "mla_moe"):
+        return {"ckv": sds((batch, seq_len, cfg.kv_lora_rank), COMPUTE_DTYPE),
+                "kpe": sds((batch, seq_len, cfg.qk_rope_head_dim),
+                           COMPUTE_DTYPE)}
     if bt == "local":
         w = min(cfg.window, seq_len)
         return {"k": sds((batch, w, kv, hd), COMPUTE_DTYPE),

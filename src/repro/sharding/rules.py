@@ -65,6 +65,7 @@ _TP_DIM_FROM_END = {
     # attention
     "wq": 2, "wk": 2, "wv": 2,            # (..., d, H, hd) -> H
     "wo": 3,                              # (..., H, hd, d) -> H
+    "wkv_b": 2,                           # MLA (..., rank, H, k) -> H
     "bq": 2, "bk": 2, "bv": 2,            # (..., H, hd)    -> H
     # dense mlp
     "w1": 1, "w3": 1,                     # (..., d, ff)    -> ff
@@ -87,7 +88,14 @@ _TP_DIM_FROM_END = {
 }
 
 # expert leaves: under an "experts" subtree the ff dim moves one inward
+# (a "shared" subtree is a dense SwiGLU and takes the table above)
 _TP_DIM_EXPERTS = {"w1": 1, "w3": 1, "w2": 2}
+
+# leaves that are state and not trained parameters: no gradient stream,
+# so never scattered over the data axes (the MoE selection bias, moved by
+# the train step from the experts' loads). MLA's "wkv_a" (d, rank + rope)
+# and "kv_norm", and the router, are replicated over 'model'.
+_NOT_SCATTERED = {"router_bias"}
 
 
 def tp_dim(path, shape, n_model: int) -> int | None:
@@ -105,7 +113,14 @@ def tp_dim(path, shape, n_model: int) -> int | None:
     return dim
 
 
+def is_state_leaf(path) -> bool:
+    """A leaf the optimizer never updates (``_NOT_SCATTERED``)."""
+    return _leaf_name(path) in _NOT_SCATTERED
+
+
 def fsdp_dim(path, shape, n_dp: int, taken: int | None) -> int | None:
+    if is_state_leaf(path):
+        return None
     start = 1 if _is_stacked(path) else 0
     best = None
     for i in range(start, len(shape)):

@@ -6,7 +6,7 @@ from repro.configs.base import SHAPES, get_arch, shape_applicable
 
 TABLE = {
     # name: (L, d_model, H, kv, d_ff, vocab)
-    "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+    "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
     "grok-1-314b": (64, 6144, 48, 8, 32768, 131072),
     "gemma3-27b": (62, 5376, 32, 16, 21504, 262144),
     "phi4-mini-3.8b": (32, 3072, 24, 8, 8192, 200064),
@@ -27,8 +27,16 @@ def test_dimensions_match_assignment(name):
 
 
 def test_moe_settings():
-    m = get_arch("moonshot-v1-16b-a3b")
+    m = get_arch("moonlight-16b-a3b")
     assert m.n_experts == 64 and m.top_k == 6
+    # d_ff is the expert width; the one dense layer has its own
+    assert m.d_ff == 1408 and m.dense_d_ff == 11264
+    assert m.n_shared_experts == 2
+    assert m.routed_scaling == 2.446 and m.experts_held == 64
+    assert (m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim,
+            m.v_head_dim) == (512, 128, 64, 128)
+    assert m.pattern_groups == ((("mla",), 1), (("mla_moe",), 26))
+    assert not m.tie_embeddings and m.rope_theta == 50_000
     g = get_arch("grok-1-314b")
     assert g.n_experts == 8 and g.top_k == 2
 

@@ -49,3 +49,19 @@ def test_bigram_statistics_match_chain():
     if n > 100:
         emp = np.array([cnt.get(i, 0) / n for i in range(8)])
         np.testing.assert_allclose(emp, np.asarray(trans[0]), atol=0.15)
+
+
+def test_zipf_frequencies_follow_the_law():
+    """Id i comes with probability proportional to 1 / (i + 1); every id
+    stays in the vocabulary, and the stream is a function of (seed, step)."""
+    c = pipeline.DataConfig(vocab=512, batch=8, seq_len=4095, seed=11,
+                            kind="zipf", zipf_a=1.0)
+    t = np.asarray(pipeline.make_batch(c, 0)["tokens"])
+    assert t.shape == (8, 4096) and t.min() >= 0 and t.max() < 512
+    np.testing.assert_array_equal(
+        t, np.asarray(pipeline.make_batch(c, 0)["tokens"]))
+    p = 1.0 / np.arange(1, 513)
+    p /= p.sum()
+    emp = np.bincount(t.reshape(-1), minlength=512) / t.size
+    np.testing.assert_allclose(emp[:4], p[:4], rtol=0.05)
+    assert emp[:8].sum() > 0.35            # the head carries the stream

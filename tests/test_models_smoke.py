@@ -70,7 +70,7 @@ def test_decode_consistent_with_forward(zoo, name):
 def test_param_count_close_to_label(zoo, name):
     full = get_arch(name)
     n = api.count_params(full)
-    label = {"moonshot-v1-16b-a3b": 28e9, "grok-1-314b": 314e9,
+    label = {"moonlight-16b-a3b": 16e9, "grok-1-314b": 314e9,
              "gemma3-27b": 27e9, "phi4-mini-3.8b": 3.8e9,
              "stablelm-1.6b": 1.6e9, "qwen2.5-3b": 3.1e9,
              "llama-3.2-vision-90b": 88e9, "recurrentgemma-9b": 8.6e9,
