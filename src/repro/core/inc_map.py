@@ -227,14 +227,56 @@ class DeviceSegment(Segment):
     (ops.device_addto_*) and reads hand back jax arrays without a host
     round trip. Host segments are *promoted* in place (``__class__``
     rewrite under ``lock`` — identity and lock object are preserved, so
-    in-flight lock holders and cross-references stay valid)."""
+    in-flight lock holders and cross-references stay valid).
+
+    A dense fp32 addTo is *staged* (``stage``) rather than launched: the
+    read of the same run that follows it runs it inside its own program
+    (``unstage``), one launch for the pair. Any other access to ``regs``
+    first launches the staged add alone (``_settle``), so a register
+    reads the same whenever anything can read it, and no staged add
+    outlives the next access to its segment. The caller holds ``lock``."""
     device = True
+    _staged = None        # (start, fp32 device chunk, scale) or None
 
     def __post_init__(self):
         if self.regs is None:
             self.regs = ops.zeros_regs(self.n_slots, device=True)
         elif not isinstance(self.regs, jnp.ndarray):
             self.regs = jnp.asarray(np.asarray(self.regs), jnp.int32)
+
+    @property
+    def regs(self):
+        self._settle()
+        return vars(self)["regs"]
+
+    @regs.setter
+    def regs(self, value):
+        vars(self).pop("_staged", None)     # overwritten: nothing to add
+        vars(self)["regs"] = value
+
+    def _settle(self) -> None:
+        st = vars(self).pop("_staged", None)
+        if st is not None:
+            vars(self)["regs"] = ops.device_addto_dense(
+                vars(self)["regs"], *st)
+            if _obs.METRICS:
+                _obs.regfile_add("alone")
+
+    def stage(self, start: int, fv: jnp.ndarray, scale) -> None:
+        """Hold the dense addTo of ``fv`` over [start, start+len) for the
+        read that follows it, after settling the one held before."""
+        self._settle()
+        self._staged = (start, fv, scale)
+
+    def unstage(self, start: int, n: int, scale):
+        """Take off and return the staged add if it is of exactly the run
+        [start, start+n) at ``scale``, else None (and keep it)."""
+        st = self._staged
+        if st is None or st[0] != start or int(st[1].shape[0]) != n \
+                or np.float32(st[2]) != np.float32(scale):
+            return None
+        del vars(self)["_staged"]
+        return st
 
 
 class SwitchMemory:
@@ -428,8 +470,7 @@ class SwitchMemory:
                 fv = (_obs.h2d(fvals[m], "regfile.addto_f32") if _obs.ON
                       else jnp.asarray(fvals[m]))
                 if len(o) and (len(o) == 1 or bool((np.diff(o) == 1).all())):
-                    seg.regs = ops.device_addto_dense(
-                        seg.regs, int(o[0]), fv, scale)
+                    seg.stage(int(o[0]), fv, scale)  # read_f32 runs it
                 else:
                     seg.regs = ops.device_addto_scatter(seg.regs, o, fv,
                                                         scale)
@@ -440,9 +481,10 @@ class SwitchMemory:
         With ``clear`` the run reads zero afterwards, a saturated register
         keeping its sentinel (the reply path's clear). The single-segment
         contiguous case (a dense GPV tensor reply) is one fused program,
-        which clears the run too; the general case gathers via ``get``,
-        dequantizes with the same reciprocal formula, so both flavors are
-        bit-identical, and clears with ``clear_read``."""
+        which clears the run too, and first runs the addTo staged on this
+        very run (``DeviceSegment.stage``); the general case gathers via
+        ``get``, dequantizes with the same reciprocal formula, so both
+        flavors are bit-identical, and clears with ``clear_read``."""
         n = len(phys)
         if n == 0:
             return jnp.zeros(0, jnp.float32), None
@@ -452,7 +494,13 @@ class SwitchMemory:
             o = np.asarray(off, np.int64)
             if seg.device and (n == 1 or bool((np.diff(o) == 1).all())):
                 with seg.lock:
-                    if clear:
+                    st = seg.unstage(int(o[0]), n, scale)
+                    if st is not None:
+                        vals, seg.regs = ops.device_addto_read_dense(
+                            seg.regs, *st, clear)
+                        if _obs.METRICS:
+                            _obs.regfile_add("read")
+                    elif clear:
                         vals, seg.regs = ops.device_read_clear_dense(
                             seg.regs, int(o[0]), n, scale)
                     else:

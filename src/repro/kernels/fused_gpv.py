@@ -18,6 +18,9 @@ verbs through ONE kernel launch each:
   - ``fused_read_clear_pallas``: the same read, and in the same program
     the reply path's copy clear of the run (sentinels kept), so the
     registers never travel to the host to be cleared.
+  - ``fused_addto_read_pallas``: a dense addTo and the read of the same
+    run that follows it (with or without the clear), the two kernels
+    above in one program: a SyncAgtr call's register work in one launch.
   - ``fused_fold_pallas``: quantize N rounds and fold them with the
     saturating add (client-side local aggregation).
 
@@ -223,3 +226,28 @@ def fused_read_clear_pallas(regs: jax.Array, start: jax.Array, n: int,
     new = jax.lax.dynamic_update_slice(
         regs, jnp.where(keep, cur, jnp.zeros_like(cur)), (start,))
     return vals, mask, new
+
+
+def fused_addto_read_pallas(regs: jax.Array, start: jax.Array,
+                            fvals: jax.Array, scale: jax.Array,
+                            bucket: int, clear: bool, *,
+                            interpret: bool | None = None
+                            ) -> tuple[jax.Array, jax.Array]:
+    """``fused_addto_pallas`` of ``fvals`` over slots [start, start+n),
+    then ``fused_read_pallas`` of that run (``fused_read_clear_pallas``
+    with ``clear``) on the result, in one program. Both kernels run over
+    ``bucket`` >= n slots, as two launches of the same run would: the
+    stream is zero-padded to it (quantize(0.0) == 0 is a sat-add no-op)
+    and the clear covers the run alone. Returns (fp32 values (n,), new
+    regs)."""
+    n = fvals.shape[0]
+    fvals = jnp.pad(fvals.astype(jnp.float32), (0, bucket - n))
+    regs = fused_addto_pallas(regs, start, fvals, scale,
+                              interpret=interpret)
+    if clear:
+        vals, _, regs = fused_read_clear_pallas(regs, start, bucket, n,
+                                                scale, interpret=interpret)
+    else:
+        vals, _ = fused_read_pallas(regs, start, bucket, scale,
+                                    interpret=interpret)
+    return vals[:n], regs

@@ -36,7 +36,9 @@ from repro.kernels.constants import (DEFAULT_BLOCK_ROWS, INT32_MAX,
 from repro.kernels.dequantize import dequantize_pallas
 from repro.kernels.flash_attn import (flash_attention_chunked_ref,
                                       flash_attention_pallas)
-from repro.kernels.fused_gpv import (fused_addto_pallas, fused_fold_pallas,
+from repro.kernels.fused_gpv import (fused_addto_pallas,
+                                     fused_addto_read_pallas,
+                                     fused_fold_pallas,
                                      fused_read_clear_pallas,
                                      fused_read_pallas, fused_scatter_pallas)
 from repro.kernels.inc_agg import sat_add_pallas
@@ -381,20 +383,29 @@ def _fused_read_jit(regs, start, n, scale, clear=False, live=None):
     return fused_read_pallas(regs, start, n, scale)
 
 
+@partial(jax.jit, static_argnames=("bucket", "clear"))
+def _fused_addto_read_jit(regs, start, fvals, scale, bucket, clear):
+    return fused_addto_read_pallas(regs, start, fvals, scale, bucket, clear)
+
+
+def _run_bucket(regs, start: int, n: int) -> int:
+    """A dense run's power-of-two length, which bounds the jit cache, or
+    ``n`` where the bucket would run past the segment end (one extra jit
+    entry)."""
+    bucket = 1 << (n - 1).bit_length()
+    return n if start + bucket > int(regs.shape[0]) else bucket
+
+
 def device_addto_dense(regs, start: int, fvals, scale) -> jax.Array:
     """Fused quantize + saturating add of an fp32 stream over the
-    contiguous slot range [start, start+len). The stream is padded to a
-    power-of-two bucket (quantize(0.0) == 0 is a sat-add no-op) to bound
-    the jit cache; when the bucket would run past the segment end, the
-    stream runs at exact length instead (one extra jit entry — never the
-    serial scatter, whose per-element loop is pathological in interpret
-    mode for a full-segment slice)."""
+    contiguous slot range [start, start+len). The stream is padded to its
+    bucket (``_run_bucket``; quantize(0.0) == 0 is a sat-add no-op), never
+    sent to the serial scatter, whose per-element loop is pathological in
+    interpret mode for a full-segment slice."""
     n = int(fvals.shape[0])
     if n == 0:
         return regs
-    bucket = 1 << (n - 1).bit_length()
-    if start + bucket > int(regs.shape[0]):
-        bucket = n
+    bucket = _run_bucket(regs, start, n)
     if bucket != n:
         fvals = jnp.pad(jnp.asarray(fvals, jnp.float32), (0, bucket - n))
     return _launch("fused_addto", n, _fused_addto_jit, regs, start,
@@ -441,13 +452,6 @@ def device_addto_int(regs, idx, vals) -> jax.Array:
                    vals)
 
 
-def _read_bucket(regs, start: int, n: int) -> int:
-    """A read's power-of-two length, or ``n`` where the bucket would run
-    past the segment end (one extra jit entry)."""
-    bucket = 1 << (n - 1).bit_length()
-    return n if start + bucket > int(regs.shape[0]) else bucket
-
-
 def device_read_dense(regs, start: int, n: int, scale
                       ) -> tuple[jax.Array, jax.Array]:
     """Fused gather + dequantize of slots [start, start+n) -> (fp32
@@ -456,7 +460,7 @@ def device_read_dense(regs, start: int, n: int, scale
     if n == 0:
         return (jnp.zeros(0, jnp.float32), jnp.zeros(0, jnp.bool_))
     vals, mask = _launch("fused_read", n, _fused_read_jit, regs, start,
-                         _read_bucket(regs, start, n), scale)
+                         _run_bucket(regs, start, n), scale)
     return vals[:n], mask[:n]
 
 
@@ -470,8 +474,21 @@ def device_read_clear_dense(regs, start: int, n: int, scale
     if n == 0:
         return jnp.zeros(0, jnp.float32), regs
     vals, _, regs = _launch("fused_read", n, _fused_read_jit, regs, start,
-                            _read_bucket(regs, start, n), scale, True, n)
+                            _run_bucket(regs, start, n), scale, True, n)
     return vals[:n], regs
+
+
+def device_addto_read_dense(regs, start: int, fvals, scale, clear: bool
+                            ) -> tuple[jax.Array, jax.Array]:
+    """``device_addto_dense`` of ``fvals`` over [start, start+n), then
+    ``device_read_dense`` of that run (``device_read_clear_dense`` with
+    ``clear``), in ONE launch (kernels/fused_gpv.py
+    ``fused_addto_read_pallas``) -> (fp32 values (n,), new regs): the
+    same kernels and buckets as the two launches, and the reply comes
+    back cut to the run."""
+    n = int(fvals.shape[0])
+    return _launch("fused_addto_read", n, _fused_addto_read_jit, regs,
+                   start, fvals, scale, _run_bucket(regs, start, n), clear)
 
 
 @partial(jax.jit, static_argnames=("block_rows",))

@@ -139,6 +139,13 @@ def regfile_clear(lane: str, n: int) -> None:
                               lane=lane).inc(n)
 
 
+def regfile_add(lane: str) -> None:
+    """One dense fp32 addTo of a device segment: ``lane="read"`` run
+    inside the program of the read that followed it, ``"alone"`` in a
+    launch of its own."""
+    _metrics.REGISTRY.counter("inc_regfile_adds_total", lane=lane).inc(1)
+
+
 # -- host<->device copies ----------------------------------------------------
 #
 # Array buffers that cross the host link in the served path, recorded at

@@ -551,7 +551,7 @@ def test_regfile_time_less_its_copies_fits_in_the_pass():
 _BENCH = Path(__file__).resolve().parents[1] / "bench"
 _READERS = ("sched.queue_wait_us_per_call", "pipeline.xfer_bytes_per_elem",
             "pipeline.device_wait_us_per_call", "regfile.host_us_per_call",
-            "regfile.device_clear_share")
+            "regfile.device_clear_share", "regfile.add_in_read_share")
 
 
 def _reader(name: str):
@@ -584,7 +584,7 @@ def test_served_path_readers_read_a_traced_rehearsal(name, traced_rehearsal):
         # every call's request pull and fused addTo, 4 B each (the CPU
         # backend keeps no host copy of a request chunk)
         assert value == pytest.approx(8.0)
-    if name == "regfile.device_clear_share":
+    if name in ("regfile.device_clear_share", "regfile.add_in_read_share"):
         assert value == 100.0
     rec = {"obs": None, "trace": None, "window": {}, "peaks": None,
            "chips": 1}

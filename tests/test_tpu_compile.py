@@ -7,7 +7,8 @@ primitives with no Mosaic lowering) — the faults interpret-mode tests
 cannot see. Every kernel of the device-resident register file is compiled
 at the real size: a 40,000-slot segment (the SwitchMemory default, paper
 §6.1), dense runs of 32,768 slots and the whole segment, reads of 1,024
-and 32,768 slots that also clear them, 4,096-entry scatter streams and
+and 32,768 slots that also clear them, the addTo and the clearing read
+of such a run in one program, 4,096-entry scatter streams and
 the 65,536-entry int bucket a full-segment run pads to, plus the
 (rows, 128)-tiled stream kernels of the ring path.
 
@@ -25,7 +26,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.constants import DEFAULT_BLOCK_ROWS, LANES
 from repro.kernels.dequantize import dequantize_pallas
-from repro.kernels.fused_gpv import (fused_addto_pallas, fused_fold_pallas,
+from repro.kernels.fused_gpv import (fused_addto_pallas,
+                                     fused_addto_read_pallas,
+                                     fused_fold_pallas,
                                      fused_read_clear_pallas,
                                      fused_read_pallas, fused_scatter_pallas)
 from repro.kernels.inc_agg import sat_add_pallas
@@ -50,6 +53,11 @@ def _read_clear(n):
         r, s, n, live, c, interpret=False)
 
 
+def _addto_read(n, clear):
+    return lambda r, s, f, c: fused_addto_read_pallas(
+        r, s, f, c, n, clear, interpret=False)
+
+
 CASES = [
     ("fused_addto_1024", _addto,
      [((SLOTS,), I32), ((), I32), ((1024,), F32), ((), F32)]),
@@ -66,6 +74,12 @@ CASES = [
      [((SLOTS,), I32), ((), I32), ((), I32), ((), F32)]),
     ("fused_read_clear_dense32768", _read_clear(32768),
      [((SLOTS,), I32), ((), I32), ((), I32), ((), F32)]),
+    ("fused_addto_read_clear_1024", _addto_read(1024, True),
+     [((SLOTS,), I32), ((), I32), ((1024,), F32), ((), F32)]),
+    ("fused_addto_read_clear_dense32768", _addto_read(32768, True),
+     [((SLOTS,), I32), ((), I32), ((32768,), F32), ((), F32)]),
+    ("fused_addto_read_dense32768", _addto_read(32768, False),
+     [((SLOTS,), I32), ((), I32), ((32768,), F32), ((), F32)]),
     ("fused_scatter_4096",
      lambda r, i, f, c: fused_scatter_pallas(r, i, f, c, interpret=False),
      [((SLOTS,), I32), ((4096,), I32), ((4096,), F32), ((), F32)]),
