@@ -1,45 +1,16 @@
-"""Device-resident GPV sweep: fused Pallas data plane vs host GPV path.
+"""SyncAgtr gradient services on the two register-file lanes.
 
-ISSUE 6's question: what does keeping the register file on device buy the
-GPV tensor path?  Both legs run the SAME pipeline, schema layer, and
-vectorized INC map — the only difference is ``device=`` on the Agg/Get
-annotations: the host leg quantizes with numpy and scatter-adds into a
-numpy register file; the device leg keeps the segment as a jax int32
-array and lowers quantize -> saturating addto (and gather -> dequantize
-on the reply) through ONE fused Pallas kernel each, with the reply coming
-back as a device-resident fp32 jax array.
-
-Correctness is the primary export on this container: the probe asserts
-the device leg is element-exact vs the host leg (identical int32
-registers; replies equal under the shared reciprocal-dequant formula)
-before any timing is trusted.  Timings are honest either way, but the
->=5x acceptance gate only arms when a compiled Pallas backend (TPU/GPU)
-is present — in interpret mode (CPU) the kernels run under the Pallas
-interpreter, which benchmarks the lane's correctness, not its speed, so
-the acceptance row reports "correctness-only PASS" instead.
-
-    PYTHONPATH=src python -m benchmarks.device_path [--smoke] [--csv]
+``DeviceGrad`` keeps its segment on the device (``device=True``: fused
+quantize + saturating addTo, and gather + dequantize on the reply);
+``HostGrad`` is the same service on the numpy register file. The chip
+benchmark's ``syncagtr_32x40k`` configuration names ``DeviceGrad`` as the
+service its serve cells drive. Speed is measured by ``bench/run.py`` on
+the chip; the two lanes' element-exact agreement is checked by
+``tests/test_device_path.py``.
 """
 from __future__ import annotations
 
-if __package__ in (None, ""):            # executed as a bare script
-    import sys
-    from pathlib import Path
-    _root = Path(__file__).resolve().parents[1]
-    sys.path.insert(0, str(_root))
-    sys.path.insert(0, str(_root / "src"))
-
-import time
-
-import numpy as np
-
 import repro.api as inc
-from repro.kernels.backend import (accelerator_present, configure_compile_cache,
-                                   pallas_mode)
-
-SIZES = (1 << 10, 1 << 12, 1 << 14, 1 << 16, 1 << 18)
-GATE_N = 1 << 18        # the acceptance-row payload size (256k)
-GATE_X = 5.0            # ISSUE 6: device >= 5x host GPV at 256k (compiled)
 
 
 @inc.service(app="DEVP-dev", name="DeviceGrad")
@@ -56,131 +27,3 @@ class HostGrad:
     def Update(self, tensor: inc.Agg[inc.FPArray](
             precision=6, clear="copy")
             ) -> {"tensor": inc.Get[inc.FPArray]}: ...
-
-
-def _fresh(device: bool, n: int):
-    rt = inc.NetRPC()
-    return rt.make_stub(DeviceGrad if device else HostGrad, n_slots=n)
-
-
-def _probe(n: int = 4096) -> None:
-    """Device leg must match the host leg element-exactly before timings
-    mean anything: identical int32 register contents, and replies equal
-    under the shared reciprocal dequantize (raw * (1/float32(scale)))."""
-    g = (np.random.RandomState(0).randn(n) * 3).astype(np.float32)
-    out = {}
-    for device in (False, True):
-        stub = _fresh(device, n)
-        stub.Update(tensor=g).result()          # grant storm
-        out[device] = np.asarray(stub.Update(tensor=g).result()["tensor"])
-    # the shared quantize oracle (f32 product, round-half-even): both legs
-    # must hold exactly these registers after the clear="copy" round
-    raw = np.rint(g * np.float32(10.0 ** 6)).astype(np.int64)
-    assert np.array_equal(out[False], raw / (10 ** 6)), \
-        "host leg diverged from the quantize oracle"
-    inv = np.float32(1.0) / np.float32(10.0 ** 6)
-    assert np.array_equal(out[True], raw.astype(np.float32) * inv), \
-        "device leg diverged from the quantize oracle (fp32 reciprocal)"
-
-
-def _time_leg(device: bool, n: int, iters: int, repeats: int) -> float:
-    """Fastest mean seconds/call of a steady-state Update (addTo + Get +
-    clear) on a fresh stub per replay; the grant-storm first call is
-    off-clock warmup."""
-    import gc
-    import jax
-    g = np.random.RandomState(1).randn(n).astype(np.float32)
-    best = None
-    for _ in range(repeats):
-        stub = _fresh(device, n)
-        jax.block_until_ready(stub.Update(tensor=g).result()["tensor"])
-        gc.collect()
-        gc.disable()
-        try:
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                jax.block_until_ready(
-                    stub.Update(tensor=g).result()["tensor"])
-            dt = (time.perf_counter() - t0) / iters
-        finally:
-            gc.enable()
-        best = dt if best is None else min(best, dt)
-    return best
-
-
-def run(sizes=SIZES, repeats: int = 3) -> tuple[list, dict]:
-    _probe()
-    mode = pallas_mode()
-    rows = [("t_device/pallas_mode", 0, f"mode={mode}")]
-    gate = None
-    for n in sizes:
-        iters = max(2, min(12, (1 << 19) // n))
-        t_host = t_dev = None
-        for _ in range(repeats):      # interleave so jitter hits both alike
-            h = _time_leg(False, n, iters, 1)
-            d = _time_leg(True, n, iters, 1)
-            t_host = h if t_host is None else min(t_host, h)
-            t_dev = d if t_dev is None else min(t_dev, d)
-        ratio = t_host / t_dev
-        if n == GATE_N:
-            gate = ratio
-        for leg, dt in (("host", t_host), ("device", t_dev)):
-            rows.append((f"t_device/{leg}/n{n}", round(dt * 1e6, 1),
-                         f"calls_per_sec={1.0 / dt:.1f}"
-                         f" elems_per_sec={n / dt:.0f}"))
-        rows.append((f"t_device/speedup/n{n}", 0,
-                     f"device_vs_host={ratio:.2f}x"))
-    acceptance = {"pallas_mode": mode, "probe": "exact"}
-    if gate is not None:
-        if accelerator_present():
-            verdict = "PASS" if gate >= GATE_X else "FAIL"
-            note = (f"device_vs_host@{GATE_N}={gate:.2f}x "
-                    f"(need >= {GATE_X:.0f}x compiled: {verdict})")
-            acceptance.update({"device_vs_host": round(gate, 2),
-                               "target": GATE_X, "verdict": verdict})
-        else:
-            # interpret mode measures the Pallas interpreter, not the
-            # lane: the gate is correctness-only until an accelerator
-            # shows up (tests/test_device_path.py xfail-not-skip marks
-            # the compiled lane for the same reason)
-            verdict = "correctness-only PASS"
-            note = (f"device_vs_host@{GATE_N}={gate:.2f}x interpret-mode "
-                    f"(no accelerator; gate = {verdict})")
-            acceptance.update({"device_vs_host": round(gate, 2),
-                               "target": GATE_X, "verdict": verdict})
-        rows.append(("t_device/acceptance", 0, note))
-    return rows, acceptance
-
-
-def main() -> None:
-    import argparse
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true",
-                    help="tiny run for CI (correct plumbing, noisy numbers)")
-    ap.add_argument("--csv", action="store_true",
-                    help="append the rows to benchmarks/results.csv")
-    ap.add_argument("--repeats", type=int, default=3)
-    args = ap.parse_args()
-    configure_compile_cache()
-    sizes = (1 << 10, 1 << 12) if args.smoke else SIZES
-    repeats = 1 if args.smoke else args.repeats
-    rows, acceptance = run(sizes, repeats=repeats)
-    lines = [",".join(str(x) for x in row) for row in rows]
-    for ln in lines:
-        print(ln)
-    from benchmarks._util import write_bench_json
-    # smoke runs export under a separate (gitignored) name so CI never
-    # overwrites the committed full-run trajectory with tiny-n noise
-    write_bench_json("smoke_device_path" if args.smoke else "device_path",
-                     {"sizes": list(sizes), "repeats": repeats,
-                      "smoke": args.smoke},
-                     rows, acceptance)
-    if args.csv:
-        from pathlib import Path
-        out = Path(__file__).resolve().parent / "results.csv"
-        with out.open("a") as f:
-            f.write("\n".join(lines) + "\n")
-
-
-if __name__ == "__main__":
-    main()

@@ -3,6 +3,8 @@
 NetRPC's claim: INC apps in ~5% of the LoC of hand-built INC systems.
 We count our examples' actual LoC (application code + NetFilter lines,
 excluding blanks/comments) against the paper's prior-art numbers.
+
+    python benchmarks/loc_table.py
 """
 from __future__ import annotations
 
@@ -73,3 +75,8 @@ def run():
         rows.append((f"loc/{app}/reduction_pct", 0,
                      round(100 * reduction, 1)))
     return rows
+
+
+if __name__ == "__main__":
+    for row in run():
+        print(",".join(str(x) for x in row))

@@ -51,9 +51,8 @@ class ChannelStats:
     admission_waits: int = 0  # submitters blocked by AIMD backpressure
     # GPV wire-path coverage: calls whose addTo stream travelled as an
     # array-native TensorSegment (vs the per-element dict path), and the
-    # total elements marshalled that way — benchmarks/wire_path.py and
-    # scheduling_report() surface these so a payload silently falling off
-    # the fast path is visible
+    # total elements marshalled that way — scheduling_report() surfaces
+    # these so a payload silently falling off the fast path is visible
     gpv_calls: int = 0
     gpv_elems: int = 0
     # client-side local aggregation (Agg[...](local_accum=N)): calls folded

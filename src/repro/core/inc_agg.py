@@ -278,8 +278,7 @@ def reduce_scatter_dim(g: jax.Array, dim: int, dp_axes: tuple[str, ...],
         # per-row shared scale, int16 wire, statically overflow-free.
         # NOTE: reduce over trailing axes directly — reshape(F, -1) would
         # merge the auto ('model')-sharded dims and force GSPMD to
-        # all-gather the full fp32 leaf (measured: +9.3 TB/step on grok;
-        # see EXPERIMENTS.md Perf, refuted-then-fixed iteration).
+        # all-gather the full fp32 leaf (measured: +9.3 TB/step on grok).
         amax = jnp.max(jnp.abs(x), axis=tuple(range(1, x.ndim)))
         amax = jax.lax.pmax(amax, dp_axes)
         scale = jnp.where(amax > 0, amax / 127.0, 1.0)

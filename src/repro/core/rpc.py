@@ -69,7 +69,7 @@ ndarrays shaped like the request; stubs built from a legacy ``Service``
 keep the historical ``{index: value}`` dict replies, and map-typed
 (STRINTMap) fields are dicts everywhere. ``set_gpv(False)`` (or
 ``REPRO_GPV=0``) forces the per-element dict path — kept as the semantic
-reference and the baseline leg of benchmarks/wire_path.py.
+reference that tests/test_wire_path.py holds the GPV path to.
 
 This module is deliberately framework-level (host-side, numpy): the
 device-resident SyncAgtr fast path is core/inc_agg.py; examples/paxos.py,
@@ -167,8 +167,8 @@ def gpv_enabled() -> bool:
 def set_gpv(enabled: bool) -> bool:
     """Enable/disable the array-native GPV wire path; returns the previous
     setting. With GPV off every tensor-shaped field is marshalled through
-    the per-element dict path — the semantic reference, and the baseline
-    leg of benchmarks/wire_path.py."""
+    the per-element dict path — the semantic reference that
+    tests/test_wire_path.py holds the GPV path to."""
     prev = _GPV[0]
     _GPV[0] = bool(enabled)
     return prev

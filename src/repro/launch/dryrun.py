@@ -17,8 +17,7 @@ Usage:
 
 Exit code 0 iff lower+compile succeeded. Prints memory_analysis (proves the
 cell fits) and cost_analysis (feeds §Roofline), plus parsed collective
-bytes. The sweep driver (launch/dryrun_all.py) runs every cell in a
-subprocess and aggregates EXPERIMENTS.md tables.
+bytes.
 """
 import argparse
 import json
@@ -49,7 +48,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, inc_mode: str,
     cfg = get_arch(arch)
     if pad_heads:
         # sharding-equivalence padding (zero heads + grad mask in prod):
-        # shapes-only measurement, see EXPERIMENTS.md section Perf
+        # shapes-only measurement
         kv = pad_kv or cfg.n_kv_heads
         assert pad_heads % kv == 0, (pad_heads, kv)
         cfg = _replace(cfg, n_heads=pad_heads, n_kv_heads=kv)

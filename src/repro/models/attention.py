@@ -10,8 +10,8 @@ Sharding notes (all under the auto 'model' axis of the step shard_map):
 
 FLOP accounting note for §Roofline: causal attention is computed dense with
 masking (train & prefill), so compiled HLO_FLOPs include the ~2x causal
-waste on the attention score terms; MODEL_FLOPS/HLO_FLOPs in EXPERIMENTS.md
-reflects it. Local-window layers avoid the waste structurally (each query
+waste on the attention score terms, which MODEL_FLOPS/HLO_FLOPs reflects.
+Local-window layers avoid the waste structurally (each query
 chunk touches exactly two W-sized KV chunks).
 """
 from __future__ import annotations

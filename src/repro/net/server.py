@@ -72,6 +72,11 @@ class SwitchServer:
         self._conns: list[socket.socket] = []
         self._down_until = 0.0
         self._stop = threading.Event()
+        # ops between their seq claim and their effect; stop() spools
+        # only once none is left, so the spool holds every effect whose
+        # seq it marks as applied (and so every effect a client saw ACKed)
+        self._inflight = 0
+        self._idle = threading.Condition(self._lock)
         self._threads: list[threading.Thread] = []
 
         if state_spool and os.path.exists(state_spool):
@@ -100,9 +105,11 @@ class SwitchServer:
         return self
 
     def stop(self, spool: bool = True) -> None:
-        if self._stop.is_set():
-            return
-        self._stop.set()
+        with self._lock:
+            if self._stop.is_set():
+                return
+            self._stop.set()             # no op claims a seq from here on
+            self._idle.wait_for(lambda: self._inflight == 0)
         if spool and self.state_spool:
             self._save_state(self.state_spool)
         with self._lock:
@@ -224,6 +231,11 @@ class SwitchServer:
 
         applied = True
         with self._lock:
+            if self._stop.is_set():
+                # shutting down: neither apply nor ACK, so the client
+                # retransmits the op to the daemon that replaces this one
+                return
+            self._inflight += 1
             seqs = self.slot_seq.setdefault(flow, [-1] * self.w_max)
             slot = seq % len(seqs)
             if op in proto.SIDE_EFFECT_OPS:
@@ -233,23 +245,28 @@ class SwitchServer:
                 else:
                     seqs[slot] = seq
         result = b""
-        if applied:
-            result = self._apply(op, meta, arrays)
-            if op in proto.SIDE_EFFECT_OPS:
-                with self._lock:
-                    self.stats["effects_applied"] += 1
-                    if self.track_effects:
-                        key = f"{flow}:{seq}"
-                        self.effect_counts[key] = \
-                            self.effect_counts.get(key, 0) + 1
-        elif op not in proto.SIDE_EFFECT_OPS:
-            result = self._apply(op, meta, arrays)  # reads re-execute
-        with self._lock:
-            self.stats["ops"] += 1
-            self.queue_len = max(0, self.queue_len - f["nfrags"])
-            if self.queue_len < self.ecn_threshold:
-                self.ecn_persist = False
-            ecn = self.ecn_persist
+        try:
+            if applied:
+                result = self._apply(op, meta, arrays)
+                if op in proto.SIDE_EFFECT_OPS:
+                    with self._lock:
+                        self.stats["effects_applied"] += 1
+                        if self.track_effects:
+                            key = f"{flow}:{seq}"
+                            self.effect_counts[key] = \
+                                self.effect_counts.get(key, 0) + 1
+            elif op not in proto.SIDE_EFFECT_OPS:
+                result = self._apply(op, meta, arrays)  # reads re-execute
+        finally:
+            with self._lock:
+                self._inflight -= 1
+                if self._inflight == 0:
+                    self._idle.notify_all()
+                self.stats["ops"] += 1
+                self.queue_len = max(0, self.queue_len - f["nfrags"])
+                if self.queue_len < self.ecn_threshold:
+                    self.ecn_persist = False
+                ecn = self.ecn_persist
         frames = proto.ack_frames(flow, seq, ecn, applied, result, self.mtu)
         with send_lock:
             for fr in frames:

@@ -29,9 +29,8 @@ pre-obs data plane is the disabled mode. Turn it on with::
     obs.disable()
 
 or set ``REPRO_OBS=1`` in the environment. ``enable(metrics=False)``
-opens the spans alone. ``benchmarks/obs_overhead.py`` (make bench-obs)
-pins disabled-mode overhead ≤2% and enabled overhead ≤10% on the
-agg_goodput hot path.
+opens the spans alone. The chip benchmark (``bench/run.py``) measures
+with obs off and reads its per-layer metrics from a separate traced run.
 """
 from __future__ import annotations
 

@@ -17,13 +17,11 @@ instrumentation with one of them::
 so the *disabled* build — the default — pays exactly one module-global
 load + bool branch per site: there is no hook object to call, no registry
 lookup, no lock, no span. That is the structural entirety of
-disabled-mode overhead, and benchmarks/obs_overhead.py pins it ≤2% on
-the agg_goodput hot path (empirically indistinguishable from noise).
+disabled-mode overhead.
 
 The record functions below run only when obs is enabled; they are
 batch-granular (one histogram observe / counter inc per pipeline pass,
-copy or kernel launch, never per element), so enabled mode stays within
-the ≤10% gate.
+copy or kernel launch, never per element).
 """
 from __future__ import annotations
 

@@ -6,6 +6,3 @@ import sys
 # tests/multidevice/* and are launched as subprocesses with their own
 # --xla_force_host_platform_device_count (see test_multidevice.py).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# tests/ itself, so test modules can import the _hypothesis_compat shim
-# regardless of pytest's import mode
-sys.path.insert(0, os.path.dirname(__file__))

@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 import jax.numpy as jnp
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ref
 from repro.kernels.backend import pallas_mode

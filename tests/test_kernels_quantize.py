@@ -7,7 +7,8 @@ property-tests the fixed-point round-trip contract of paper §5.2.1.
 import numpy as np
 import pytest
 import jax.numpy as jnp
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels import ref
 from repro.kernels.backend import accelerator_present, pallas_mode

@@ -357,6 +357,7 @@ def test_workers4_snapshot_validates_and_reports_quantiles(spans):
     assert ch["latency_p99_us"] >= ch["latency_p50_us"]
     assert ch["acks"] >= 1
     assert snap["switch"]["total_slots"] > 0
+    assert "cache_hit_ratio" in snap["switch"]["apps"]["OBS-T"]
     assert snap["switch"]["segments"]
     hists = snap["metrics"]["histograms"]
     assert any(k.startswith("inc_pipeline_pass_us") for k in hists)

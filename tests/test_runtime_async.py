@@ -10,7 +10,8 @@ import time
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core.netfilter import NetFilter
 from repro.core.rpc import Field, NetRPC, Service
 from repro.core.runtime import DrainPolicy, IncRuntime
@@ -80,12 +81,16 @@ def test_time_trigger_bounds_delay():
 
 
 def test_window_trigger_drains_when_window_has_room():
-    rt = IncRuntime()                   # defaults: eager AIMD window
+    # defaults: eager AIMD window. The clock stands still, so the call
+    # never ages past max_delay and the time trigger cannot fire first
+    rt = IncRuntime(clock=lambda: 0.0)
     try:
         stub = rt.make_stub(monitor_service())
         f = stub.call_async("Push", {"kvs": {"w": 1}})
         wait_done([f])
-        assert stub.channels["Push"].stats.drain_triggers["window"] >= 1
+        triggers = stub.channels["Push"].stats.drain_triggers
+        assert triggers["window"] >= 1
+        assert triggers["time"] == 0
     finally:
         rt.close()
 

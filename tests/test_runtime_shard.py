@@ -17,7 +17,8 @@ import time
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from repro.core.netfilter import NetFilter
 from repro.core.rpc import Field, NetRPC, Service
 from repro.core.runtime import DrainPolicy, IncRuntime, _ChannelQueue

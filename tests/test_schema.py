@@ -21,7 +21,8 @@ import sys
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import example, given, settings
+from hypothesis import strategies as st
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -287,7 +288,9 @@ def _reqs_from_ops(ops):
     return reqs
 
 
-@settings(max_examples=12)
+# deadline=None: the first example compiles its programs (~0.9 s on a
+# CPU), so hypothesis's 200 ms deadline fails on the compile, not on a case
+@settings(max_examples=12, deadline=None)
 @given(st.integers(0, 2),                       # precision
        st.sampled_from(CLEARS),
        st.sampled_from(MODIFIES),
@@ -297,6 +300,8 @@ def _reqs_from_ops(ops):
                                              st.integers(-40, 40)),
                                    min_size=1, max_size=4)),
                 min_size=1, max_size=10))
+@example(precision=2, clear="copy", modify=("add", 3), threshold=3,
+         ops=[(0, 0, [(6, 15), (6, 15)]), (1, 1, [(6, 37), (2, -27)])])
 def test_typed_stub_equals_legacy_stub(precision, clear, modify, threshold,
                                        ops):
     """Same random schema + request stream through (a) the legacy string

@@ -5,7 +5,8 @@ windows): under ANY loss pattern, every packet's side effect is applied
 EXACTLY once, using only w_max bits of per-flow switch state.
 """
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.transport import (AimdState, ClientFlow, FlipBitSwitch,
                                   LossyLink, Packet, flip_of, run_flow)

@@ -8,11 +8,13 @@ properties are identical: no side effect is ever double-applied
 (``duplicate_effects == {}``), registers match an in-process oracle
 element-exactly, and no call ever hangs past its deadline.
 """
+import threading
 import time
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.inc_map import ServerAgent, SwitchMemory
 from repro.kernels.constants import INT32_MAX
@@ -23,9 +25,9 @@ from repro.net import protocol as proto
 GEO = dict(n_segments=4, seg_slots=256)
 
 
-def _stack(spec=None, **kw):
+def _stack(spec=None, spool=None, **kw):
     """server [+ proxy] + transport + memory; returns (srv, px, t, mem)."""
-    srv = SwitchServer(track_effects=True, **GEO).start()
+    srv = SwitchServer(state_spool=spool, track_effects=True, **GEO).start()
     px = FaultProxy(srv.address, spec).start() if spec else None
     addr = px.address if px else srv.address
     t = WireTransport(addr, flow_id=kw.pop("flow_id", 1), w_max=8,
@@ -40,6 +42,13 @@ def _teardown(srv, px, t):
     if px:
         px.stop()
     srv.stop()
+
+
+def _respawn(address, spool):
+    """A new daemon on a stopped one's TCP address, restored from its spool."""
+    host, port = address
+    return SwitchServer(host=host, port=port, state_spool=spool,
+                        track_effects=True, **GEO).start()
 
 
 # -- exactly-once under chaos -------------------------------------------------
@@ -67,11 +76,14 @@ def test_exactly_once_under_chaos(loss, seed):
         _teardown(srv, px, t)
 
 
-def test_crash_restart_replay():
-    """Daemon crash mid-stream: clients reconnect and replay; state
-    survives; still exactly-once."""
+@pytest.mark.parametrize("restart", ["crash", "spool"])
+def test_crash_restart_replay(restart, tmp_path):
+    """Daemon crash mid-stream, or a graceful stop and a new daemon
+    restored from the state spool on the same address: clients reconnect
+    and replay; state survives; still exactly-once."""
     spec = FaultSpec(seed=3, loss=0.2, dup=0.1, reorder=0.1)
-    srv, px, t, mem = _stack(spec)
+    spool = str(tmp_path / "switchd.pkl")
+    srv, px, t, mem = _stack(spec, spool=spool if restart == "spool" else None)
     try:
         assert mem.reserve(1, 64)
         phys = np.arange(64, dtype=np.int64)
@@ -81,7 +93,11 @@ def test_crash_restart_replay():
             vals = rng.integers(-50, 50, size=64).astype(np.int32)
             mem.addto(phys, vals)
             expect += vals
-        srv.crash(0.3)                      # refuse service; state survives
+        if restart == "crash":
+            srv.crash(0.3)                  # refuse service; state survives
+        else:
+            srv.stop()                      # spools registers + seqs
+            srv = _respawn(srv.address, spool)
         for _ in range(30):
             vals = rng.integers(-50, 50, size=64).astype(np.int32)
             mem.addto(phys, vals)
@@ -94,6 +110,45 @@ def test_crash_restart_replay():
         assert not mem.fallback_active
     finally:
         _teardown(srv, px, t)
+
+
+def test_stop_spools_the_op_it_is_applying(tmp_path):
+    """A graceful stop that lands while an addTo is being applied spools
+    that addTo too: the daemon restarted from the spool holds it, though
+    the old one may have ACKed it after the stop began."""
+    spool = str(tmp_path / "switchd.pkl")
+    srv = SwitchServer(state_spool=spool, track_effects=True, **GEO).start()
+    t = WireTransport(srv.address, flow_id=1, w_max=8, rto_base=0.02,
+                      call_timeout=30.0)
+    mem = RemoteSwitchMemory(t, **GEO)
+    entered, release = threading.Event(), threading.Event()
+    apply = srv._apply
+
+    def held_apply(op, meta, arrays):
+        if op == proto.OP_ADDTO:
+            entered.set()
+            release.wait(10)
+        return apply(op, meta, arrays)
+
+    try:
+        assert mem.reserve(1, 32)
+        phys = np.arange(32, dtype=np.int64)
+        srv._apply = held_apply
+        mem.addto(phys, np.full(32, 7, np.int32))
+        assert entered.wait(10)
+        stopper = threading.Thread(target=srv.stop)
+        stopper.start()
+        time.sleep(0.2)         # time enough for a stop that did not wait
+        release.set()
+        stopper.join(10)
+        assert not stopper.is_alive()
+        srv = _respawn(srv.address, spool)
+        assert mem.get(phys).tolist() == [7] * 32
+        assert t.ctrl("stats")["duplicate_effects"] == {}
+    finally:
+        release.set()
+        t.close()
+        srv.stop(spool=False)
 
 
 def test_oracle_equivalence_mixed_ops():

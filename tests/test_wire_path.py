@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import example, given, settings
+from hypothesis import strategies as st
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -277,13 +278,27 @@ CLEARS = ("nop", "copy")
 MODIFIES = ("nop", ("max", 30), ("add", 5))
 
 
-@settings(max_examples=10)
+# deadline=None: the first example compiles its programs (~0.6-0.9 s on a
+# CPU), so hypothesis's 200 ms deadline fails on the compile, not on a case
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2),                       # precision
        st.sampled_from(CLEARS),
        st.sampled_from(MODIFIES),
        st.integers(0, 1),                       # collide?
        st.lists(st.lists(st.floats(-60.0, 60.0), min_size=1, max_size=9),
                 min_size=1, max_size=6))
+# precision 0 with a -0.5 payload: a halfway case at the coarsest scale
+@example(precision=0, clear="copy", modify=("add", 5), collide=0,
+         payloads=[[1.1771700249927793e-124, -0.5, 46.74120342387266],
+                   [-2.506903900182774e-254, 3.4416725526901153e-75,
+                    42.086081749086176, 2.2250738585e-313,
+                    48.307492998586966, 41.595659675524075,
+                    21.77819980854528],
+                   [57.63833550374572, -10.363782002275961]])
+# the SyncAgtr shape at precision 6: the same chunk pushed twice, copy clear
+@example(precision=6, clear="copy", modify="nop", collide=0,
+         payloads=[[5.292157, 1.200472, 2.936214, 6.72268, 5.602674,
+                    -2.931834, 2.850265, -0.454072, -0.309657]] * 2)
 def test_gpv_equals_dict_path_end_to_end(precision, clear, modify, collide,
                                          payloads):
     tag = modify if isinstance(modify, str) else f"{modify[0]}{modify[1]}"
@@ -494,6 +509,13 @@ def test_pure_query_array_rides_gpv_path(gpv_on):
 @settings(max_examples=10)
 @given(st.integers(1, 24), st.integers(0, 4),
        st.lists(st.floats(-100.0, 100.0), min_size=1, max_size=24))
+# a full-width read at precision 6
+@example(n=24, precision=6,
+         xs=[48.730361, -18.352692, -15.845153, -32.189059, 25.962229,
+             -69.046161, 52.344353, -22.836207, 9.571173, -7.481111,
+             43.863238, -61.804221, -9.672516, -11.521631, 34.013083,
+             -32.996738, -5.172846, -26.335753, 1.266412, 17.484456,
+             -33.018575, 34.341711, 27.047722, 15.07483])
 def test_pure_query_gpv_equals_dict_reference(n, precision, xs):
     """Array-shaped ReadMostly requests: the TensorSegment read must be
     element-identical to the {i: x} dict reference path (REPRO_GPV=0),
