@@ -40,6 +40,7 @@ import zlib
 from collections import Counter
 from dataclasses import dataclass, field
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -138,6 +139,18 @@ def quantize_values(values, scale) -> np.ndarray:
     if arr is not None and arr.dtype.kind in "biuf":
         return quantize_stream(arr, scale)
     return np.array(quantize_scalar_ref(values, scale), np.int64)
+
+
+def host_values(x, site: str) -> np.ndarray:
+    """An update stream's values on the host, in their own dtype. A device
+    stream is pulled here, one ``d2h`` copy recorded at ``site``: the paths
+    that need host values (a miss, a collision, a host segment, a flush of
+    more than one chunk) take the caller's device buffer no further."""
+    return _obs.d2h(x, site) if _obs.ON else np.asarray(x)
+
+
+def _device_f32(x) -> bool:
+    return isinstance(x, jax.Array) and x.dtype == jnp.float32
 
 
 # -- version-counted dicts (snapshot invalidation) ----------------------------
@@ -261,6 +274,11 @@ class DeviceSegment(Segment):
                 vars(self)["regs"], *st)
             if _obs.METRICS:
                 _obs.regfile_add("alone")
+
+    def holds(self, x: jax.Array) -> bool:
+        """True when ``x`` lives on the device the registers live on."""
+        regs = vars(self)["regs"]
+        return isinstance(regs, jax.Array) and x.devices() == regs.devices()
 
     def stage(self, start: int, fv: jnp.ndarray, scale) -> None:
         """Hold the dense addTo of ``fv`` over [start, start+len) for the
@@ -446,22 +464,43 @@ class SwitchMemory:
                     seg.regs = ops.dense_addto_host(seg.regs, off, v)
             pos += take
 
-    def addto_f32(self, phys: np.ndarray, fvals: np.ndarray, scale) -> None:
+    def addto_f32(self, phys: np.ndarray, fvals, scale) -> None:
         """Fused quantize + saturating scatter-add of an fp32 update
         stream — the device-resident transmit verb. Contiguous per-segment
         runs (the dense GPV tensor case) lower to one fused slice-add
         kernel; anything else (gaps, duplicates) to the fused serial
         scatter, which matches the sequential oracle exactly. Non-device
         segments quantize on host and take the int path (robustness only;
-        the agent routes f32 streams here just for device partitions)."""
+        the agent routes f32 streams here just for device partitions).
+
+        A float32 device stream on one device segment's contiguous run,
+        on the registers' device, is staged as the caller's buffer, with
+        no copy (``inc_request_elems_total{lane="device"}``): the segment
+        holds that buffer until the staged add runs, so the caller must
+        not delete or donate it before the run's next access, or before
+        ``settle_lent`` (a served call's pass ends so). Every other
+        stream is staged or scattered from host values, as it came or
+        pulled here (``lane="host"``)."""
         seg_ix, off = self._locate(np.asarray(phys))
         if not len(seg_ix):
             return
-        fvals = np.asarray(fvals, np.float32)
+        if not _device_f32(fvals):
+            fvals = np.asarray(fvals, np.float32)
         for s, m in self._seg_groups(seg_ix):
             seg = self.segments[s]
             o = np.asarray(off[m], np.int32)
             with seg.lock:
+                run = len(o) == 1 or bool((np.diff(o) == 1).all())
+                if isinstance(fvals, jax.Array):
+                    if (isinstance(m, slice) and seg.device and run
+                            and seg.holds(fvals)):
+                        seg.stage(int(o[0]), fvals, scale)
+                        if _obs.METRICS:
+                            _obs.request_elems("device", len(o))
+                        continue
+                    fvals = host_values(fvals, "regfile.addto_f32")
+                if _obs.METRICS:
+                    _obs.request_elems("host", len(o))
                 if not seg.device:
                     seg.regs = ops.sparse_addto_host(
                         seg.regs, o,
@@ -469,11 +508,23 @@ class SwitchMemory:
                     continue
                 fv = (_obs.h2d(fvals[m], "regfile.addto_f32") if _obs.ON
                       else jnp.asarray(fvals[m]))
-                if len(o) and (len(o) == 1 or bool((np.diff(o) == 1).all())):
+                if run:
                     seg.stage(int(o[0]), fv, scale)  # read_f32 runs it
                 else:
                     seg.regs = ops.device_addto_scatter(seg.regs, o, fv,
                                                         scale)
+
+    def settle_lent(self, x: jax.Array, start: int, n: int) -> None:
+        """Run now the add staged over [start, start+n) from the caller's
+        buffer ``x``, so that no segment holds ``x`` any longer; any other
+        staged add still waits for its read."""
+        for s in range(start // self.seg_slots,
+                       -(-(start + n) // self.seg_slots)):
+            seg = self.segments[s]
+            if seg.device:
+                with seg.lock:
+                    if seg._staged is not None and seg._staged[1] is x:
+                        seg._settle()
 
     def read_f32(self, phys: np.ndarray, scale, clear: bool = False
                  ) -> tuple[jnp.ndarray, None]:
@@ -836,17 +887,20 @@ class ServerAgent:
         ``addto_batch(logical, quantize_stream(fvals, scale))`` — which is
         exactly what a non-device agent falls back to."""
         if not self.device:
-            self.addto_batch(logical,
-                             quantize_stream(np.asarray(fvals), scale))
+            self.addto_batch(logical, quantize_stream(
+                host_values(fvals, "regfile.addto_f32"), scale))
             return
         logical = np.asarray(logical, np.uint32)
-        fvals = np.asarray(fvals, np.float32)
         n = len(logical)
         if n == 0:
             return
         q = logical.astype(np.int64)
         hit, slotv = self._map_lookup(q)
         n_hit = int(hit.sum())
+        if n_hit < n or not _device_f32(fvals):
+            # a miss needs host values; all hit, a device stream goes on
+            fvals = np.asarray(host_values(fvals, "regfile.addto_f32"),
+                               np.float32)
         if n_hit:
             if n_hit == n:
                 phys, fv = self.base + slotv, fvals
@@ -860,6 +914,12 @@ class ServerAgent:
             self._route_miss(logical[miss],
                              quantize_stream(fvals[miss], scale))
         self._account(logical, n)
+
+    @_locked
+    def settle_lent(self, fvals: jax.Array) -> None:
+        """Run at once an add of the partition staged from the caller's
+        device buffer ``fvals`` (``SwitchMemory.settle_lent``)."""
+        self.switch.settle_lent(fvals, self.base, self.capacity)
 
     @_locked(op="read_dev")
     def read_batch_dev(self, logical: np.ndarray, scale,
@@ -1211,14 +1271,17 @@ class ClientAgent:
         """Dense index -> address resolution keeping values as raw fp32
         (the device-resident lane — quantization happens inside the fused
         switch kernel): returns (logical addrs, fp32 values, collision
-        host-path pairs). Collision elements quantize on host since they
-        ride the spill path. Address routing is identical to
-        ``resolve_dense``; only the value dtype differs."""
+        host-path pairs). A float32 device stream comes back as it came,
+        unless a collision needs its host values. Collision elements
+        quantize on host since they ride the spill path. Address routing
+        is identical to ``resolve_dense``; only the value dtype differs."""
         self._ensure_dense(n)
         logs = self._dense_log[:n]
-        fdata = np.asarray(fdata, np.float32).reshape(-1)
+        if not _device_f32(fdata):
+            fdata = np.asarray(fdata, np.float32).reshape(-1)
         coll = self._dense_coll_arr
         if len(coll) and coll[0] < n:       # collision host path (rare)
+            fdata = host_values(fdata, "rpc.stream_items")
             m = coll[coll < n]
             qcoll = quantize_values(fdata[m], scale)
             spills = list(zip(m.tolist(), qcoll.tolist()))

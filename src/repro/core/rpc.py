@@ -84,11 +84,13 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
 from repro.core.channel import Channel, Controller
 from repro.core.clear_policy import POLICIES
-from repro.core.inc_map import hash_key, quantize_stream, quantize_values
+from repro.core.inc_map import (hash_key, host_values, quantize_stream,
+                                quantize_values)
 from repro.core.netfilter import NetFilter
 from repro.kernels import ref
 from repro.kernels.ops import (device_fold_rounds, fold_rounds,
@@ -181,18 +183,29 @@ class TensorSegment:
     resolution -> Map.addTo -> Map.get/clear -> dequantize — without ever
     materializing a per-element dict. Elements are addressed by their flat
     index (identity hash, see ClientAgent.resolve_dense); values travel as
-    int64 fixed point once ``quantize`` runs."""
+    int64 fixed point once ``quantize`` runs.
 
-    data: np.ndarray                  # raveled request values, input dtype
+    A device request (a ``jax.Array``) stays the caller's buffer: the
+    device lane hands it to the register file as it is, and it is pulled
+    to the host only when a path needs host values (``host()``: host
+    quantize, Stream.modify, local_accum; a collision, a miss, a host
+    segment or a flush of more than one chunk pull it further down)."""
+
+    data: np.ndarray | jax.Array      # raveled request values, input dtype
     shape: tuple[int, ...]            # original field shape (reply shape)
     qvals: np.ndarray | None = None   # int64 fixed-point (phase 1/2)
 
     def __len__(self) -> int:
         return self.data.shape[0]
 
+    def host(self) -> np.ndarray:
+        """The values on the host; a device request is pulled here, once."""
+        self.data = host_values(self.data, "rpc.stream_items")
+        return self.data
+
     def quantize(self, scale) -> None:
         if self.qvals is None:
-            self.qvals = quantize_values(self.data, scale)
+            self.qvals = quantize_values(self.host(), scale)
 
 
 def _int32_checked(q: np.ndarray) -> np.ndarray:
@@ -211,7 +224,8 @@ def _stream_items(request: dict, msg_field: str) -> "dict | TensorSegment":
 
     Fast path (GPV): non-dict values that convert to a numeric ndarray of
     rank >= 1 (ndarrays, lists/tuples of numbers, jax arrays) become a
-    :class:`TensorSegment` and stay arrays through the whole pipeline.
+    :class:`TensorSegment` and stay arrays through the whole pipeline; a
+    jax array stays on its device.
     Dict path: dict values (explicit key -> value maps), scalars, and
     non-numeric payloads are marshalled per element as ``{key: value}``,
     exactly as before the GPV path existed (also forced for everything by
@@ -223,8 +237,12 @@ def _stream_items(request: dict, msg_field: str) -> "dict | TensorSegment":
         return {}
     if isinstance(v, dict):
         return v
-    # a device array is pulled to the host here
-    arr = _obs.d2h(v, "rpc.stream_items") if _obs.ON else np.asarray(v)
+    if _GPV[0] and isinstance(v, jax.Array) and v.ndim >= 1 \
+            and v.dtype.kind in "biuf":
+        return TensorSegment(data=v if v.ndim == 1 else v.reshape(-1),
+                             shape=v.shape)
+    # any other device array is pulled to the host here
+    arr = host_values(v, "rpc.stream_items")
     if _GPV[0] and arr.ndim >= 1 and arr.dtype.kind in "biuf":
         return TensorSegment(data=arr.reshape(-1), shape=arr.shape)
     return {i: x for i, x in enumerate(arr.ravel())}
@@ -244,7 +262,7 @@ class _PlannedCall:
     logs: np.ndarray | None = None              # resolved logical addrs
     vals: np.ndarray | None = None
     device_plan: bool = False                   # device-resident GPV lane ok
-    fvals: np.ndarray | None = None             # unquantized fp32 stream
+    fvals: "np.ndarray | jax.Array | None" = None   # unquantized fp32
     #         ^ set instead of ``vals`` when the update rides the device
     #           lane: quantization happens inside the fused switch kernel
     spills: list = field(default_factory=list)  # collision host-path pairs
@@ -287,11 +305,14 @@ class _MapOpBuffer:
             self._chunks.append(("i", np.asarray(logs, np.uint32),
                                  np.asarray(vals, np.int64)))
 
-    def addto_f(self, logs: np.ndarray, fvals: np.ndarray, scale) -> None:
-        """Buffer an unquantized fp32 update stream (device lane)."""
+    def addto_f(self, logs: np.ndarray, fvals, scale) -> None:
+        """Buffer an unquantized fp32 update stream (device lane); a
+        device stream is kept as it is."""
         if len(logs):
-            self._chunks.append(("f", np.asarray(logs, np.uint32),
-                                 np.asarray(fvals, np.float32), scale))
+            if not isinstance(fvals, jax.Array):
+                fvals = np.asarray(fvals, np.float32)
+            self._chunks.append(("f", np.asarray(logs, np.uint32), fvals,
+                                 scale))
 
     def add_scalar(self, addr: int, delta: int) -> None:
         """Single-register update (CntFwd counters) without the per-call
@@ -301,12 +322,17 @@ class _MapOpBuffer:
     def spill(self, pairs: list[tuple[int, int]]) -> None:
         self._spills.extend(pairs)
 
-    def flush(self) -> None:
+    def flush(self, read_next: bool = False) -> None:
+        """Apply the buffered updates. ``read_next`` says a read of the
+        same pass follows at once, and runs a request staged as the
+        caller's device buffer; without one it runs here, so that no
+        buffer is held past the call that lent it."""
         if self._spills:
             # one folded spill/stats update for the whole flush, not a
             # Python loop per collision item
             self.server.spill_host(self._spills)
             self._spills = []
+        n_extra = len(self._extra)
         if self._extra:
             # counter addresses are disjoint from data keys, so appending
             # them after the data chunks preserves observable semantics
@@ -322,10 +348,17 @@ class _MapOpBuffer:
         kinds = {c[0] for c in chunks}
         if kinds == {"f"} and len({c[3] for c in chunks}) == 1:
             # pure device-lane flush at one precision: ONE fused
-            # quantize+addto batch, values never quantize on host
+            # quantize+addto batch, values never quantize on host; one
+            # chunk goes as it came (a device request stays on device)
+            if len(chunks) == 1:
+                self.server.addto_batch_f32(*chunks[0][1:])
+                if not read_next and isinstance(chunks[0][2], jax.Array):
+                    self.server.settle_lent(chunks[0][2])
+                return
             self.server.addto_batch_f32(
                 np.concatenate([c[1] for c in chunks]),
-                np.concatenate([c[2] for c in chunks]), chunks[0][3])
+                np.concatenate([host_values(c[2], "rpc.stream_items")
+                                for c in chunks]), chunks[0][3])
             return
         if "f" in kinds:
             # mixed flush (or mixed precisions): demote fp32 chunks in
@@ -333,10 +366,13 @@ class _MapOpBuffer:
             # fp32 streams (pinned by tests/test_wire_path.py), so the
             # one concatenated int batch preserves ordering semantics
             chunks = [("i", c[1],
-                       quantize_stream(c[2], c[3]).astype(np.int64))
+                       quantize_stream(host_values(c[2], "rpc.stream_items"),
+                                       c[3]).astype(np.int64))
                       if c[0] == "f" else c for c in chunks]
-        self.server.addto_batch(np.concatenate([c[1] for c in chunks]),
-                                np.concatenate([c[2] for c in chunks]))
+        logs = np.concatenate([c[1] for c in chunks])
+        if _obs.METRICS and len(logs) > n_extra:
+            _obs.request_elems("host", len(logs) - n_extra)
+        self.server.addto_batch(logs, np.concatenate([c[2] for c in chunks]))
 
 
 # How long a pipeline pass may wait for a channel's plane lock before
@@ -622,7 +658,7 @@ def _phase_execute(channel: Channel, host_server: Server,
 
             # reply path: Map.get (+ clear policy)
             if c.nf.get != "nop" and c.forwarded:
-                buf.flush()      # this get must observe every earlier addTo
+                buf.flush(read_next=True)  # this get observes every addTo
                 fname = c.nf.get.split(".")[-1]
                 scale = 10 ** c.nf.precision
                 seg = (c.items if isinstance(c.items, TensorSegment) else
@@ -1006,9 +1042,10 @@ class _FoldBuffer:
             if self.mode == "dev":
                 if items.data.dtype != np.float32:
                     return False
-                self.frounds.append(items.data)
+                self.frounds.append(items.host())
             else:
-                self.qrounds.append(self._round_quantized(items.data, scale))
+                self.qrounds.append(self._round_quantized(items.host(),
+                                                          scale))
         else:
             if self.mode is None:
                 self.mode = "dict"

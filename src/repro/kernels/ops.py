@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import os
 import time
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -335,6 +335,25 @@ def zeros_regs(n_slots: int, device: bool = False):
 # metrics are on (the jitted bodies run only when traced, so the record
 # sits here, per call).
 
+@lru_cache(maxsize=256)
+def _device_scalar(kind: type, v, dtype=None) -> jax.Array:
+    """``jnp.asarray(v, dtype)`` of a Python number, made once per value.
+
+    A Python scalar handed to a jitted program is copied to the device on
+    every call; a device array is not. ``jnp.asarray`` keeps the weak type
+    jit gives a Python scalar, so the cached operand compiles and computes
+    as the number did. Keyed by type as well (``1 == 1.0`` as dict keys);
+    the values are partition offsets, run lengths and precisions, so a
+    small LRU holds them all."""
+    return jnp.asarray(v, dtype)
+
+
+def _operand(v, dtype=None) -> jax.Array:
+    """A dynamic scalar operand of a device-lane program (a Python
+    number), as a cached device scalar."""
+    return _device_scalar(type(v), v, dtype)
+
+
 def _launch(kernel: str, n: int, fn, *args):
     t0 = time.perf_counter() if _obs.METRICS else 0.0
     out = fn(*args)
@@ -358,7 +377,7 @@ def device_fold_rounds(frounds: list, scale) -> jax.Array:
         (_obs.h2d(f, "rpc.fold", jnp.float32) if _obs.ON
          else jnp.asarray(f, jnp.float32)).reshape(-1) for f in frounds])
     return _launch("fused_fold", int(fstack.size), _fused_fold_jit, fstack,
-                   jnp.asarray(scale, jnp.float32))
+                   _operand(scale, jnp.float32))
 
 
 @jax.jit
@@ -408,8 +427,8 @@ def device_addto_dense(regs, start: int, fvals, scale) -> jax.Array:
     bucket = _run_bucket(regs, start, n)
     if bucket != n:
         fvals = jnp.pad(jnp.asarray(fvals, jnp.float32), (0, bucket - n))
-    return _launch("fused_addto", n, _fused_addto_jit, regs, start,
-                   fvals, scale)
+    return _launch("fused_addto", n, _fused_addto_jit, regs,
+                   _operand(start), fvals, _operand(scale))
 
 
 def device_addto_scatter(regs, idx, fvals, scale) -> jax.Array:
@@ -426,7 +445,7 @@ def device_addto_scatter(regs, idx, fvals, scale) -> jax.Array:
         idx = jnp.pad(idx, (0, bucket - k))
         fvals = jnp.pad(jnp.asarray(fvals, jnp.float32), (0, bucket - k))
     return _launch("fused_scatter", k, _fused_scatter_jit, regs, idx,
-                   fvals, scale)
+                   fvals, _operand(scale))
 
 
 def device_addto_int(regs, idx, vals) -> jax.Array:
@@ -459,8 +478,9 @@ def device_read_dense(regs, start: int, n: int, scale
     a power-of-two length and sliced back."""
     if n == 0:
         return (jnp.zeros(0, jnp.float32), jnp.zeros(0, jnp.bool_))
-    vals, mask = _launch("fused_read", n, _fused_read_jit, regs, start,
-                         _run_bucket(regs, start, n), scale)
+    vals, mask = _launch("fused_read", n, _fused_read_jit, regs,
+                         _operand(start), _run_bucket(regs, start, n),
+                         _operand(scale))
     return vals[:n], mask[:n]
 
 
@@ -473,8 +493,9 @@ def device_read_clear_dense(regs, start: int, n: int, scale
     the run is read, never cleared."""
     if n == 0:
         return jnp.zeros(0, jnp.float32), regs
-    vals, _, regs = _launch("fused_read", n, _fused_read_jit, regs, start,
-                            _run_bucket(regs, start, n), scale, True, n)
+    vals, _, regs = _launch("fused_read", n, _fused_read_jit, regs,
+                            _operand(start), _run_bucket(regs, start, n),
+                            _operand(scale), True, _operand(n))
     return vals[:n], regs
 
 
@@ -488,7 +509,8 @@ def device_addto_read_dense(regs, start: int, fvals, scale, clear: bool
     back cut to the run."""
     n = int(fvals.shape[0])
     return _launch("fused_addto_read", n, _fused_addto_read_jit, regs,
-                   start, fvals, scale, _run_bucket(regs, start, n), clear)
+                   _operand(start), fvals, _operand(scale),
+                   _run_bucket(regs, start, n), clear)
 
 
 @partial(jax.jit, static_argnames=("block_rows",))

@@ -144,6 +144,13 @@ def regfile_add(lane: str) -> None:
     _metrics.REGISTRY.counter("inc_regfile_adds_total", lane=lane).inc(1)
 
 
+def request_elems(lane: str, n: int) -> None:
+    """Request elements of an addTo flush, by how they reached the
+    register file: ``lane="device"`` as the caller's device buffer (no
+    copy), ``"host"`` through a host copy or the host quantizer."""
+    _metrics.REGISTRY.counter("inc_request_elems_total", lane=lane).inc(n)
+
+
 # -- host<->device copies ----------------------------------------------------
 #
 # Array buffers that cross the host link in the served path, recorded at
